@@ -131,6 +131,7 @@ from repro.configs import get_config, reduce_config
 from repro.core import resolve_spec
 from repro.data import SyntheticTranslation
 from repro.obs import PHASES
+from repro.runtime import configure_compile_cache
 from repro.serving import (IMPL_CHOICES, FaultPlan, SamplingParams,
                            SLATarget, TraceConfig, deploy, impl_routes,
                            latency_percentiles, pages_needed)
@@ -805,6 +806,7 @@ def main():
                          "miscounts, or throughput below the "
                          "single-replica baseline")
     args = ap.parse_args()
+    configure_compile_cache()
     pols = ([p.strip() for p in args.policies.split(",") if p.strip()]
             if args.policies else None)
     run(smoke=args.smoke, json_path=args.json, horizon=args.horizon,

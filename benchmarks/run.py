@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sweeps where supported")
     args = ap.parse_args()
+    from repro.runtime import configure_compile_cache
+    configure_compile_cache()
 
     print("name,us_per_call,derived")
     from . import (bench_fasst, bench_qmm, bench_quant_formats,

@@ -134,6 +134,11 @@ class ReplicaRouter:
             sum(e.num_pending for e in self.replicas),
             sum(e.max_pending or 0 for e in self.replicas))
 
+    def replica_of(self, request_id: int) -> int:
+        """Index of the replica that serves ``request_id``, a global id
+        live from ``submit`` until its output is handed out."""
+        return self._owner[request_id][0]
+
     def _remap(self, ridx: int,
                outs: Sequence[RequestOutput]) -> List[RequestOutput]:
         remapped = []

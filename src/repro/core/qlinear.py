@@ -156,7 +156,9 @@ def qmatmul(
             # spec must never silently run bf16 activations
             x = _fake_quant_act(x, act, act_scale, compute_dtype)
         if impl == "pallas" and w.fmt in ("int4", "fp4", "nf4") \
-                and w.data.ndim == 2:
+                and w.data.ndim == 2 and w.q_axis == -2:
+            # (K, N) weights quantized along K only: embedding-style
+            # tables (q_axis=-1, e.g. an untied lm_head) stay on XLA
             from ..kernels import ops as kops  # lazy: avoid import cycle
             y = kops.qmm(x, w, compute_dtype=compute_dtype)
         else:

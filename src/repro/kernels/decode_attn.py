@@ -11,6 +11,8 @@ Layouts (prepared by kernels.ops.decode_attention):
   q        (B, Hkv, G, d)   G = query heads per KV head, padded to >=8
   k_codes  (B, Hkv, S, d)   int8        k_scales (B, Hkv, S) f32
   v_codes  (B, Hkv, S, d)   int8        v_scales (B, Hkv, S) f32
+  (scales are viewed as (B, Hkv, 1, S) so their (1, 1, 1, bs) block
+  meets the TPU tiling rule)
   lengths  (B,) int32       valid KV length per sequence
 Grid (B, Hkv, S/bs), sequence innermost ("arbitrary").
 """
@@ -23,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import compiler_params
 
 __all__ = ["decode_attn_call"]
 
@@ -43,10 +43,11 @@ def _kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                     # (G, d)
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]   # (bs, d)
+    k = k_ref[0, 0].astype(jnp.float32)                     # (bs, d)
+    # per-token scales are a (1, bs) row: they scale score columns
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale      # (G, bs)
+        preferred_element_type=jnp.float32) * sm_scale * ks_ref[0, 0]
 
     pos = s * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     valid = pos < len_ref[b]
@@ -59,9 +60,10 @@ def _kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
     p = jnp.where(valid, p, 0.0)
 
     l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]   # (bs, d)
+    v = v_ref[0, 0].astype(jnp.float32)                     # (bs, d)
+    # (p * s) @ v == p @ (v * s[:, None])
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p * vs_ref[0, 0], v, preferred_element_type=jnp.float32)
 
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -88,9 +90,9 @@ def decode_attn_call(q, k_codes, k_scales, v_codes, v_scales, lengths, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, h, s, L: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, d), lambda b, h, s, L: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, h, s, L: (b, h, s)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s, L: (b, h, 0, s)),
             pl.BlockSpec((1, 1, bs, d), lambda b, h, s, L: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, h, s, L: (b, h, s)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b, h, s, L: (b, h, 0, s)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, s, L: (b, h, 0, 0)),
         scratch_shapes=[
@@ -103,8 +105,8 @@ def decode_attn_call(q, k_codes, k_scales, v_codes, v_scales, lengths, *,
         functools.partial(_kernel, bs=bs, sm_scale=sm_scale),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attn_int8kv",
-    )(lengths, q, k_codes, k_scales, v_codes, v_scales)
+    )(lengths, q, k_codes, k_scales[:, :, None], v_codes, v_scales[:, :, None])

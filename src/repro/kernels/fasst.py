@@ -21,8 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["MODES", "fasst_act_call", "fasst_softmax_call"]
 
@@ -71,7 +70,7 @@ def fasst_act_call(x, *, mode: str, bm: int, out_dtype=None,
         in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, C), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name=f"fasst_{mode}",
@@ -104,7 +103,7 @@ def fasst_softmax_call(x, *, bm: int, valid_cols: int = -1, scale: float = 1.0,
         in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, C), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="fasst_softmax",

@@ -67,10 +67,15 @@ def qmm(x: jnp.ndarray, w: QTensor, *, compute_dtype=jnp.bfloat16,
     x2 = x.reshape(-1, K)
     M = x2.shape[0]
 
-    bk = _pick_tile(K, bk, multiple=sub_block if sub_block % 2 == 0 or
-                    fmt.bits != 4 else sub_block * 2)
-    if fmt.bits == 4 and bk % 2:
-        bk *= 2
+    if fmt.bits == 4 and sub_block % 2:
+        raise ValueError(f"packed {w.fmt} qmm needs an even block size, "
+                         f"got {sub_block}")
+    interpret = interpret_mode()
+    if N % 128 and not interpret:
+        # a TPU block's lane dim must be a multiple of 128 (or all of
+        # N, which would not fit VMEM for a vocab-sized head)
+        raise ValueError(f"qmm on TPU needs N % 128 == 0, got N={N}")
+    bk = _pick_tile(K, bk, multiple=sub_block)
     bn = _pick_tile(N, bn, multiple=128 if N % 128 == 0 else 1)
     Mp = _round_up(max(M, 1), bm) if M % bm else M
     if Mp != M:
@@ -79,8 +84,22 @@ def qmm(x: jnp.ndarray, w: QTensor, *, compute_dtype=jnp.bfloat16,
     y = _qmm.qmm_kernel_call(
         x2.astype(compute_dtype), w.data, w.block_scales(),
         fmt_name=w.fmt, sub_block=sub_block, bm=min(bm, Mp), bn=bn, bk=bk,
-        out_dtype=compute_dtype, interpret=interpret_mode())
+        out_dtype=compute_dtype, interpret=interpret)
     return y[:M].reshape(*lead, N)
+
+
+# VMEM bytes one fasst block may take: Mosaic's scoped VMEM limit is
+# 16 MiB, and a (256, 8192) block with its double buffers and f32
+# temporaries does not fit in it
+_FASST_VMEM_BUDGET = 8 * 2**20
+
+
+def _fasst_rows(C: int, in_dtype, out_dtype) -> int:
+    """Most rows (a multiple of 8) whose block fits the VMEM budget:
+    double-buffered input and output tiles plus two f32 temporaries."""
+    per_row = C * (2 * (jnp.dtype(in_dtype).itemsize
+                        + jnp.dtype(out_dtype).itemsize) + 8)
+    return max(8, _FASST_VMEM_BUDGET // per_row // 8 * 8)
 
 
 def fasst(x: jnp.ndarray, mode: str, *, out_dtype=None, bm: int = 256):
@@ -89,7 +108,7 @@ def fasst(x: jnp.ndarray, mode: str, *, out_dtype=None, bm: int = 256):
     C = shape[-1]
     x2 = x.reshape(-1, C)
     M = x2.shape[0]
-    bm = _pick_tile(M, bm)
+    bm = _pick_tile(M, min(bm, _fasst_rows(C, x.dtype, out_dtype or x.dtype)))
     if M % bm:
         pad = _round_up(M, bm) - M
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))

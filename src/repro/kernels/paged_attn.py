@@ -12,7 +12,9 @@ index-map use).
 Layouts (prepared by kernels.ops.paged_decode_attention):
   q          (B, Hkv, G, d)    G = query heads per KV head, padded >= 8
   k_pages    (P, Hkv, ps, d)   int8 codes or bf16   [v_pages likewise]
-  k_scales   (P, Hkv, ps) f32  absent on the bf16 path
+  k_scales   (P, Hkv, ps) f32  absent on the bf16 path; viewed as
+                               (P, Hkv, 1, ps) so a page's block
+                               (1, 1, 1, ps) meets the TPU tiling rule
   block_tables (B, maxp) int32 page ids; out-of-chain entries must name
                                a reserved trash page (masked by length)
   lengths    (B,) int32        valid token count per sequence
@@ -30,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import compiler_params
 
 __all__ = ["paged_attn_call"]
 
@@ -67,15 +67,16 @@ def _kernel(
     q = q_ref[0, 0].astype(jnp.float32)  # (G, d)
     k = k_ref[0, 0].astype(jnp.float32)  # (ps, d)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, 0][:, None]
-        v = v * vs_ref[0, 0][:, None]
     scores = (
         jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         * sm_scale
     )  # (G, ps)
+    if quantized:
+        # per-token scales arrive as a (1, ps) row: scale the score
+        # columns (q . k_t * s_t) instead of the K rows
+        scores = scores * ks_ref[0, 0]
 
     # page p of the chain holds token positions [p*ps, (p+1)*ps)
     pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -89,8 +90,11 @@ def _kernel(
     prob = jnp.where(valid, prob, 0.0)
 
     l_new = l_ref[:, :1] * alpha + jnp.sum(prob, axis=-1, keepdims=True)
+    # sum_t p_t * (v_t * s_t) == (p * s) @ v: the V scales weight the
+    # probabilities, not the V rows
+    pv = prob * vs_ref[0, 0] if quantized else prob
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        prob, v, preferred_element_type=jnp.float32
+        pv, v, preferred_element_type=jnp.float32
     )
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -127,18 +131,18 @@ def paged_attn_call(
         return (tbl[b, p], h, 0, 0)
 
     def sc_map(b, h, p, lens, tbl):
-        return (tbl[b, p], h, 0)
+        return (tbl[b, p], h, 0, 0)
 
     def q_map(b, h, p, lens, tbl):
         return (b, h, 0, 0)
 
     kv_spec = pl.BlockSpec((1, 1, ps, d), kv_map)
-    sc_spec = pl.BlockSpec((1, 1, ps), sc_map)
+    sc_spec = pl.BlockSpec((1, 1, 1, ps), sc_map)
     q_spec = pl.BlockSpec((1, 1, G, d), q_map)
 
     if quantized:
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
-        args = [q, k_pages, k_scales, v_pages, v_scales]
+        args = [q, k_pages, k_scales[:, :, None], v_pages, v_scales[:, :, None]]
     else:
         in_specs = [q_spec, kv_spec, kv_spec]
         args = [q, k_pages, v_pages]
@@ -181,7 +185,7 @@ def paged_attn_call(
         kernel,
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -28,37 +28,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import get_format
-from .compat import compiler_params
 
 __all__ = ["qmm_kernel_call"]
 
 
-def _dequant_tile(w_ref, s_ref, fmt_name: str, bk: int, sub_block: int):
-    """Unpack + dequantize one (bk, bn) weight tile in VMEM, f32 out."""
-    fmt = get_format(fmt_name)
-    if fmt.bits == 4:
-        packed = w_ref[...]                       # (bk//2, bn) uint8
-        lo = packed & jnp.uint8(0x0F)
-        hi = (packed >> 4) & jnp.uint8(0x0F)
-        codes = jnp.stack([lo, hi], axis=1).reshape(bk, packed.shape[-1])
-        if fmt.kind == "int":                     # int4: two's complement
-            vals = ((codes.astype(jnp.int8) ^ jnp.int8(8)) - jnp.int8(8)
-                    ).astype(jnp.float32)
-        else:                                     # fp4 / nf4: 16-way codebook
-            # unrolled compare-select chain — VPU-friendly, no gather
-            vals = jnp.zeros(codes.shape, jnp.float32)
-            for i, cval in enumerate(fmt.codebook):
-                vals = jnp.where(codes == jnp.uint8(i),
-                                 jnp.float32(cval), vals)
-    elif fmt.name == "int8":
-        vals = w_ref[...].astype(jnp.float32)     # (bk, bn) int8
-    else:                                         # fp8 storage
-        vals = w_ref[...].astype(jnp.float32)
+def _decode_nibbles(codes, fmt):
+    """int32 nibble codes (0..15) -> f32 values of a 4-bit format."""
+    if fmt.kind == "int":                         # int4: two's complement
+        return ((codes ^ 8) - 8).astype(jnp.float32)
+    # fp4 / nf4: 16-way codebook as an unrolled compare-select chain on
+    # int32 codes — VPU-friendly, no gather
+    vals = jnp.zeros(codes.shape, jnp.float32)
+    for i, cval in enumerate(fmt.codebook):
+        vals = jnp.where(codes == i, jnp.float32(cval), vals)
+    return vals
 
-    scales = s_ref[...]                           # (bk//sub_block, bn) f32
-    bn = vals.shape[-1]
-    vals = vals.reshape(bk // sub_block, sub_block, bn) * scales[:, None, :]
-    return vals.reshape(bk, bn)
+
+def _scale_rows(vals, scales, rows_per_scale: int):
+    """Multiply each run of ``rows_per_scale`` rows by its scale row."""
+    r, bn = vals.shape
+    vals = vals.reshape(r // rows_per_scale, rows_per_scale, bn)
+    return (vals * scales[:, None, :]).reshape(r, bn)
 
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
@@ -69,11 +59,31 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _dequant_tile(w_ref, s_ref, fmt_name, bk, sub_block)
-    x = x_ref[...].astype(jnp.float32)
-    # MXU dot with f32 accumulate into the output-stationary scratch
-    acc_ref[...] += jnp.dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                            preferred_element_type=jnp.float32)
+    fmt = get_format(fmt_name)
+    scales = s_ref[...]                           # (bk//sub_block, bn) f32
+    x = x_ref[...].astype(jnp.bfloat16)
+    if fmt.bits == 4:
+        # packed row r holds K rows 2r (low nibble) and 2r+1 (high
+        # nibble); the wrapper permuted x's columns within the tile so
+        # that its first half multiplies the low nibbles and its second
+        # half the high ones — no in-VMEM interleave. Unpacking widens
+        # to int32 first: Mosaic does not shift 8-bit vectors on v5e.
+        packed = w_ref[...].astype(jnp.int32)     # (bk//2, bn)
+        half = bk // 2
+        w_lo = _scale_rows(_decode_nibbles(packed & 0xF, fmt), scales,
+                           sub_block // 2)
+        w_hi = _scale_rows(_decode_nibbles((packed >> 4) & 0xF, fmt), scales,
+                           sub_block // 2)
+        # MXU dots with f32 accumulate into the output-stationary scratch
+        acc_ref[...] += (
+            jnp.dot(x[:, :half], w_lo.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+            + jnp.dot(x[:, half:], w_hi.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32))
+    else:                                         # int8 / fp8 storage
+        w = _scale_rows(w_ref[...].astype(jnp.float32), scales, sub_block)
+        acc_ref[...] += jnp.dot(x, w.astype(jnp.bfloat16),
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -88,12 +98,16 @@ def qmm_kernel_call(x, packed, scales, *, fmt_name: str, sub_block: int,
     """x:(M,K) @ dequant(packed,scales):(K,N) -> (M,N).
 
     Preconditions (enforced by kernels.ops): M%bm==0, N%bn==0, K%bk==0,
-    bk%sub_block==0, and bk even for packed 4-bit formats.
+    bk%sub_block==0, and sub_block even for packed 4-bit formats.
     """
     M, K = x.shape
     fmt = get_format(fmt_name)
     N = packed.shape[-1]
     pack = 2 if fmt.bits == 4 else 1
+    if pack == 2:
+        # within each K tile, even columns first, then odd columns: the
+        # order the kernel's low/high nibble halves consume them
+        x = x.reshape(M, K // bk, bk // 2, 2).swapaxes(-1, -2).reshape(M, K)
 
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
@@ -108,7 +122,7 @@ def qmm_kernel_call(x, packed, scales, *, fmt_name: str, sub_block: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"qmm_{fmt_name}",

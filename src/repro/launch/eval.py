@@ -34,6 +34,7 @@ from ..data import LANG_CODES, SyntheticTranslation, pairs as fig9_pairs
 from ..eval import make_report, quant_sweep, render_markdown, save
 from ..eval.suite import _ordered_langs
 from ..models import Ctx, build_model
+from ..runtime import configure_compile_cache
 from ..optim import warmup_cosine
 from ..serving import IMPL_CHOICES, impl_routes
 from ..train import TrainLoop, make_train_step
@@ -146,6 +147,7 @@ def main(argv=None):
                     help="max allowed bf16->int8 mean-BLEU drop when both "
                          "formats run (negative disables the check)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     formats = [f.strip() for f in args.formats.split(",") if f.strip()]
     # fail on argument typos BEFORE the multi-minute training fit

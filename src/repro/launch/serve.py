@@ -47,6 +47,7 @@ from ..configs import REGISTRY
 from ..core import ALIASES, resolve_spec
 from ..data import SyntheticTranslation
 from ..obs import MetricsServer
+from ..runtime import configure_compile_cache
 from ..serving import (IMPL_CHOICES, EngineSaturated, SamplingParams,
                        SLATarget, TraceConfig, deploy, impl_routes)
 
@@ -122,6 +123,7 @@ def main():
     ap.add_argument("--top-p", type=float, default=1.0)
     ap.add_argument("--eos-id", type=int, default=None)
     args = ap.parse_args()
+    configure_compile_cache()
 
     resolve_spec(args.policy)        # fail on typos before any build work
     if args.draft_spec is not None:

@@ -17,6 +17,7 @@ from ..configs import REGISTRY, get_config, reduce_config
 from ..data import SyntheticLM, SyntheticTranslation
 from ..models import Ctx, build_model
 from ..optim import warmup_cosine
+from ..runtime import configure_compile_cache
 from ..train import TrainLoop, make_train_step
 
 
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

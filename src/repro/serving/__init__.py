@@ -40,8 +40,8 @@ from .faults import FaultPlan
 from .metrics import EngineMetrics, SLATarget, merge_metrics
 from .paged_cache import PageAllocator, pages_needed
 from .params import (FINISH_REASONS, GREEDY, EngineSaturated, Request,
-                     RequestOutput, RequestStats, SamplingParams,
-                     latency_percentiles)
+                     RequestOutput, RequestStats, RoundBudgetExhausted,
+                     SamplingParams, latency_percentiles)
 from .pipeline import IMPL_CHOICES, TranslationPipeline, deploy, impl_routes
 from .sampler import ERR_TOKEN
 from .spec_decode import DraftArm, accept_longest_prefix, build_draft_arm
@@ -52,5 +52,5 @@ __all__ = ["ServeEngine", "greedy_generate", "translate", "SamplingParams",
            "PageAllocator", "pages_needed", "impl_routes", "IMPL_CHOICES",
            "DraftArm", "accept_longest_prefix", "build_draft_arm",
            "EngineMetrics", "SLATarget", "merge_metrics", "EngineSaturated",
-           "FaultPlan",
+           "RoundBudgetExhausted", "FaultPlan",
            "FINISH_REASONS", "ERR_TOKEN", "TraceConfig", "Tracer"]

@@ -158,7 +158,7 @@ from ..obs.metrics import render_prometheus
 from .metrics import EngineMetrics, SLAController, SLATarget
 from .paged_cache import TRASH_PAGE, PageAllocator, paged_insert, pages_needed
 from .params import (GREEDY, EngineSaturated, Request, RequestOutput,
-                     RequestStats, SamplingParams)
+                     RequestStats, RoundBudgetExhausted, SamplingParams)
 from .sampler import ERR_TOKEN, sample_tokens, sample_tokens_scan
 from .spec_decode import DraftArm, accept_longest_prefix
 
@@ -406,21 +406,37 @@ class ServeEngine:
 
         self._step_fn = self._jit(_step)
 
-        def _prefill_paged(p, inputs, lengths, slot_ids, page_rows, cache,
-                           temps, top_ks, top_ps, keys):
-            # one jitted call admits a whole group: batched prefill into a
-            # prompt-sized dense mini-cache, fused first-token sampling,
-            # then scatter of the mini-cache into page chains / cross rows
+        def _prefill_group(p, inputs, lengths, slot_ids, page_rows, cache):
+            # batched prefill of one admission group into a prompt-sized
+            # dense mini-cache, scattered into page chains / cross rows;
+            # also returns the logits that choose each first token
             n, s_bucket = inputs[self._tkey].shape
             mini = model.init_cache(n, s_bucket, kv_dtype)
             mini, logits = model.prefill(self.ctx, p, mini, inputs)
             last = logits[jnp.arange(n), lengths - 1].astype(jnp.float32)
-            toks = sample_tokens(last, temps, top_ks, top_ps, keys,
-                                 jnp.zeros((n,), jnp.int32))
             cache = paged_insert(cache, mini, slot_ids, page_rows, lengths)
+            return cache, last
+
+        def _prefill_paged(p, inputs, lengths, slot_ids, page_rows, cache,
+                           temps, top_ks, top_ps, keys):
+            # one jitted call admits a whole group, first-token sampling
+            # fused in
+            cache, last = _prefill_group(p, inputs, lengths, slot_ids,
+                                         page_rows, cache)
+            toks = sample_tokens(last, temps, top_ks, top_ps, keys,
+                                 jnp.zeros((last.shape[0],), jnp.int32))
             return cache, toks
 
         self._prefill_paged_fn = self._jit(_prefill_paged)
+
+        def _forced(p, inputs, lengths, slot_ids, page_rows, cache, feed):
+            cache, last = _prefill_group(p, inputs, lengths, slot_ids,
+                                         page_rows, cache)
+            _, logits = decode_block(model, self.ctx, p, feed, cache)
+            return jnp.concatenate(
+                [last[:, None], logits[slot_ids].astype(jnp.float32)], axis=1)
+
+        self._forced_fn = self._jit(_forced)
 
         if draft is not None:
             # the draft arm's prefill mirrors the target's but discards
@@ -661,7 +677,7 @@ class ServeEngine:
             while out is None:
                 try:
                     next(rounds)
-                except (StopIteration, RuntimeError):
+                except (StopIteration, RoundBudgetExhausted):
                     break   # drained (abort) or round budget exhausted
                 while buf:
                     yield buf.pop(0)
@@ -696,6 +712,51 @@ class ServeEngine:
     def _take_finished(self) -> List[RequestOutput]:
         out, self._finished = self._finished, []
         return out
+
+    def teacher_forced_logits(self, requests, tokens) -> jax.Array:
+        """Logits of this engine's own routes under teacher forcing.
+
+        Admits ``requests`` (B=1 batch dicts, as ``submit`` takes them)
+        as ONE prefill group into slots 0..n-1, as a burst of them
+        admits, then feeds ``tokens`` (n, T) through ``decode_block``
+        on the engine's page pool, Ctx kernel routes and mesh. Row t of
+        the (n, T, vocab) f32 result holds the logits that choose token
+        t given the prompt and ``tokens[:, :t]``: the prefill's for
+        t = 0, a decode step's after. Two engines scored on one stream
+        show how far their numerics part at every step, where their own
+        greedy streams would part at the first near tie. Needs an idle
+        paged engine without a draft arm; leaves it as it was.
+        """
+        if not self.paged or self.draft is not None:
+            raise ValueError("teacher_forced_logits needs a paged engine "
+                             "without a draft arm")
+        if self.num_active or self.num_pending:
+            raise ValueError("teacher_forced_logits needs an idle engine")
+        tokens = np.asarray(tokens, np.int32)
+        n, T = tokens.shape
+        if len(requests) != n or not 0 < n <= self.n_slots:
+            raise ValueError(f"{len(requests)} requests and {n} token rows; "
+                             f"need the same number, 1 to {self.n_slots}")
+        toks = [jnp.atleast_2d(jnp.asarray(r[self._tkey])) for r in requests]
+        lens = [t.shape[1] for t in toks]
+        if max(lens) + T - 1 > self.max_len:
+            raise ValueError(f"prompt {max(lens)} + {T - 1} forced tokens "
+                             f"exceed max_len={self.max_len}")
+        chains = [self.allocator.alloc_chain(
+            pages_needed(n_tok + T - 1, self.page_size)) for n_tok in lens]
+        try:
+            rows = np.zeros((n, self.max_pages), np.int32)
+            for i, chain in enumerate(chains):
+                rows[i, :len(chain)] = chain
+            feed = np.zeros((self.n_slots, T - 1), np.int32)
+            feed[:n] = tokens[:, :-1]
+            return self._forced_fn(
+                self.params, self._group_inputs(toks, requests),
+                jnp.asarray(lens, jnp.int32), jnp.arange(n, dtype=jnp.int32),
+                jnp.asarray(rows), self.cache, jnp.asarray(feed))
+        finally:
+            for chain in chains:
+                self.allocator.free_chain(chain)
 
     def _now(self) -> float:
         """The engine clock: wall time plus any fault-injected skew
@@ -1014,7 +1075,8 @@ class ServeEngine:
                 if rounds > max_rounds:
                     if tr is not None:
                         self._round_end()
-                    raise RuntimeError("run_until_drained did not converge")
+                    raise RoundBudgetExhausted(
+                        "run_until_drained did not converge")
                 if pending is not None:
                     alive_d, rem_d, block, Kd, seqs = pending
                     pending = None
@@ -1762,6 +1824,21 @@ class ServeEngine:
             self._queue.popleft()
         return group
 
+    def _group_inputs(self, toks, sides) -> dict:
+        """Prefill inputs of one admission group: the feeds ``toks``
+        right-padded to the longest one's bucket, their true lengths,
+        and the side inputs (sources, frames, image embeddings) of the
+        members' input dicts ``sides``, stacked."""
+        pad_to = self._bucket(max(t.shape[1] for t in toks))
+        inputs = {self._tkey: jnp.concatenate(
+            [jnp.pad(t, ((0, 0), (0, pad_to - t.shape[1]))) for t in toks])}
+        inputs["lengths"] = jnp.asarray([t.shape[1] for t in toks],
+                                        jnp.int32)
+        for k in ("src_tokens", "frames", "img_embeds"):
+            if k in sides[0]:
+                inputs[k] = jnp.concatenate([s[k] for s in sides])
+        return inputs
+
     def _admit_group(self, group: List[Request]):
         """Admit a same-shape group under ONE jitted prefill+insert
         call. A resumed (previously preempted) request prefills its
@@ -1782,13 +1859,7 @@ class ServeEngine:
             p0 = time.perf_counter()
         toks = [self._feed_tokens(r) for r in group]
         true_lens = [t.shape[1] for t in toks]
-        pad_to = self._bucket(max(true_lens))
-        inputs = {self._tkey: jnp.concatenate(
-            [jnp.pad(t, ((0, 0), (0, pad_to - t.shape[1]))) for t in toks])}
-        inputs["lengths"] = jnp.asarray(true_lens, jnp.int32)
-        for k in ("src_tokens", "frames", "img_embeds"):
-            if k in group[0].inputs:
-                inputs[k] = jnp.concatenate([r.inputs[k] for r in group])
+        inputs = self._group_inputs(toks, [r.inputs for r in group])
         chains = []
         rows = np.zeros((n, self.max_pages), np.int32)  # 0 = trash page
         for i, r in enumerate(group):

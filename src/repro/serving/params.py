@@ -44,7 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 __all__ = ["SamplingParams", "GREEDY", "Request", "RequestOutput",
            "RequestStats", "FINISH_REASONS", "EngineSaturated",
-           "latency_percentiles"]
+           "RoundBudgetExhausted", "latency_percentiles"]
 
 FINISH_REASONS = ("eos", "length", "abort", "deadline", "preempted_limit",
                   "error")
@@ -63,6 +63,12 @@ class EngineSaturated(RuntimeError):
             f"engine saturated: {pending} requests pending >= "
             f"max_pending={limit}; retry after draining (engine.step() / "
             f"stream()) or deploy with a larger max_pending")
+
+
+class RoundBudgetExhausted(RuntimeError):
+    """The round loop ran ``max_rounds`` rounds without draining. Typed
+    so that a caller that ends quietly on it (``stream_request``) lets
+    every other error, a device fault above all, propagate."""
 
 
 @dataclasses.dataclass(frozen=True)

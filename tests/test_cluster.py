@@ -116,7 +116,7 @@ def test_router_spreads_load_and_remaps_ids(lm):
     gids = [router.submit({"tokens": p}, sp)
             for p, sp in zip((P1, P2, P3, P4), sps)]
     assert gids == [0, 1, 2, 3]
-    assert [router._owner[g][0] for g in gids] == [0, 1, 0, 1]
+    assert [router.replica_of(g) for g in gids] == [0, 1, 0, 1]
     outs = {o.request_id: o for o in router.run_until_drained()}
     assert sorted(outs) == gids
     for i, g in enumerate(gids):
@@ -158,7 +158,7 @@ def test_saturated_replica_failover_then_cluster_raise(lm):
             for p in (P1, P2, P3, P4)]
     # placement so far: r0=[P1 active, P3 queued], r1=[P2 active,
     # P4 queued] — alternating by competition count
-    assert [router._owner[g][0] for g in gids] == [0, 1, 0, 1]
+    assert [router.replica_of(g) for g in gids] == [0, 1, 0, 1]
     # 5th submit ties on load, tries r0 first (index), bounces off its
     # full queue, and fails over to r1's deeper queue
     g4 = router.submit({"tokens": P1}, sp)

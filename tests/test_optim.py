@@ -67,13 +67,13 @@ def test_schedules_shape():
 
 def test_compressed_psum_single_device():
     """shard_map over a 1-device mesh: compression is near-lossless psum."""
-    mesh = jax.make_mesh((1,), ("dp",))
+    mesh = jax.make_mesh((1,), ("dp",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((256, 8)),
                           jnp.float32)}
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(lambda t: compressed_psum(t, "dp"), mesh=mesh,
+    f = jax.shard_map(lambda t: compressed_psum(t, "dp"), mesh=mesh,
                   in_specs=(P(),), out_specs=P())
     out = f(g)
     rel = float(jnp.linalg.norm(out["w"] - g["w"]) / jnp.linalg.norm(g["w"]))

@@ -307,6 +307,37 @@ def test_group_admission_is_batched_and_bounded():
         assert cache_size() == 1
 
 
+@pytest.mark.parametrize("arch", ["gemma3-1b", "nllb600m"])
+def test_teacher_forced_logits_pick_the_engine_streams(arch):
+    """Scored on its own greedy streams, a paged engine's teacher-forced
+    logits (one burst, its own prefill and decode routes) choose those
+    streams at every step, and the engine is left idle with no page
+    held."""
+    rc, model, params = _lm(arch)
+    key = "src_tokens" if arch == "nllb600m" else "tokens"
+    n_tok = rc.enc_len if arch == "nllb600m" else 4
+    reqs = [{key: jax.random.randint(jax.random.PRNGKey(i), (1, n_tok), 0,
+                                     rc.vocab_size)} for i in range(4)]
+    if arch == "nllb600m":
+        reqs = [dict(r, tgt_in=jnp.full((1, 1), 8, jnp.int32)) for r in reqs]
+    eng = ServeEngine(model, params, slots=4, max_len=16, ctx=CTX,
+                      paged=True, page_size=4, horizon=4)
+    ids = [eng.submit(r, SamplingParams(max_new_tokens=6)) for r in reqs]
+    outs = {o.request_id: o for o in eng.run_until_drained()}
+    streams = [outs[i].token_ids for i in ids]
+    logits = eng.teacher_forced_logits(reqs, streams)
+    assert logits.shape[:2] == (4, 6) and logits.dtype == jnp.float32
+    assert jnp.argmax(logits, axis=-1).tolist() == streams
+    assert eng.allocator.pages_in_use == 0
+    eng.allocator.check()
+    eng.submit(reqs[0], SamplingParams(max_new_tokens=6))
+    with pytest.raises(ValueError, match="idle"):
+        eng.teacher_forced_logits(reqs[:1], streams[:1])
+    dense = ServeEngine(model, params, slots=4, max_len=16, ctx=CTX)
+    with pytest.raises(ValueError, match="paged"):
+        dense.teacher_forced_logits(reqs[:1], streams[:1])
+
+
 def test_group_admission_mixed_lengths_buckets():
     """Different prompt lengths in one burst: the group pads to the head
     request's bucket; distinct buckets admit as separate groups."""

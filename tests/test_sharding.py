@@ -68,7 +68,8 @@ def test_quantized_tree_shardable():
     params = {"layers": {"attn": {"wq": jnp.ones((2, 64, 32))}}}
     qp = quantize_tree(params, PRESETS["int4"])
     from repro.parallel.sharding import param_shardings
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = param_shardings(mesh, qp)
     flat = jax.tree_util.tree_flatten_with_path(sh)[0]
     keys = {jax.tree_util.keystr(k): v for k, v in flat}
@@ -88,7 +89,8 @@ def test_eight_device_lowering_subprocess():
         from repro.launch.dryrun import build_cell
         from repro.parallel import set_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for arch in ("internlm2-20b", "olmoe-1b-7b"):
             cfg = reduce_config(get_config(arch), d_model=64, num_layers=2,
                                 num_heads=4, num_kv_heads=2, head_dim=16,

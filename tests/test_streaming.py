@@ -26,9 +26,9 @@ import pytest
 from repro.configs import REGISTRY, reduce_config
 from repro.eval import report as report_mod
 from repro.models import Ctx, build_model
-from repro.serving import (EngineMetrics, SamplingParams, ServeEngine,
-                           SLATarget, TraceConfig, deploy, greedy_generate,
-                           translate)
+from repro.serving import (EngineMetrics, RoundBudgetExhausted,
+                           SamplingParams, ServeEngine, SLATarget,
+                           TraceConfig, deploy, greedy_generate, translate)
 from repro.serving.metrics import SLAController
 
 CTX = Ctx(compute_dtype=jnp.float32)
@@ -197,6 +197,60 @@ def test_stream_request_tokens_match_drained_output():
     rest = eng.run_until_drained()
     assert [o.request_id for o in rest] == [other]
     assert rest[0].token_ids == refs[1].token_ids
+
+
+class _DeviceFault(RuntimeError):
+    """Stands in for jax.errors.JaxRuntimeError (a RuntimeError)."""
+
+
+def test_stream_request_propagates_device_errors():
+    """A fault raised inside a round (a device OOM surfaces as a
+    RuntimeError subclass) reaches the streaming caller; it does not
+    end the stream as if the request had drained."""
+    assert issubclass(jax.errors.JaxRuntimeError, RuntimeError)
+    rc, model, params = _lm()
+    p1, _ = _prompts(rc)
+    eng = ServeEngine(model, params, slots=2, max_len=24, ctx=CTX,
+                      horizon=4)
+
+    def fault(*args, **kwargs):
+        raise _DeviceFault("device lost")
+
+    eng._dispatch_horizon = fault
+    gen = eng.stream_request({"tokens": p1}, SamplingParams(max_new_tokens=6))
+    with pytest.raises(_DeviceFault):
+        list(gen)
+
+
+def test_stream_request_ends_quietly_on_round_budget():
+    """Round-budget exhaustion is typed, and stream_request still ends
+    the stream on it: the tokens so far, then a None output."""
+    rc, model, params = _lm()
+    p1, _ = _prompts(rc)
+    sp = SamplingParams(max_new_tokens=12)
+    eng = ServeEngine(model, params, slots=2, max_len=24, ctx=CTX,
+                      horizon=4)
+    eng.submit({"tokens": p1}, sp)
+    with pytest.raises(RoundBudgetExhausted):
+        eng.run_until_drained(max_steps=1)
+
+    ref = ServeEngine(model, params, slots=2, max_len=24, ctx=CTX,
+                      horizon=4)
+    full = _drain_by_id(ref, [ref.submit({"tokens": p1}, sp)])[0].token_ids
+    eng = ServeEngine(model, params, slots=2, max_len=24, ctx=CTX,
+                      horizon=4)
+    rounds = eng._rounds
+    eng._rounds = lambda horizon=None: rounds(horizon, max_rounds=1)
+    gen = eng.stream_request({"tokens": p1}, sp)
+    toks = []
+    while True:
+        try:
+            toks.append(next(gen))
+        except StopIteration as fin:
+            out = fin.value
+            break
+    assert out is None
+    assert 0 < len(toks) < len(full) and toks == full[:len(toks)]
 
 
 def test_stream_yields_per_finish_and_on_round_admission():
