@@ -1,0 +1,5 @@
+"""decode_mfu, in a cell where it moves tokens_per_s."""
+
+
+def read(run):
+    return run.metric("decode_mfu")
