@@ -6,6 +6,15 @@ many-to-many translation driven by target-language code tokens; the MoE
 variant (Fig. 3b) swaps the FFN for top-k experts. Whisper-base reuses the
 same skeleton with a stub conv frontend (input_specs feeds precomputed
 frame embeddings) and cross-attention from the decoder.
+
+Every layer's work sits in a ``jax.named_scope`` named as its ``Ctx.dot``
+sites are (``embed``, ``enc.attn``, ``enc.ffn``, ``dec.cross.kv``,
+``dec.attn``, ``dec.cross``, ``dec.ffn``, ``head``), and the serving
+decoder's layer loop in ``dec.layers``, which names what the loop itself
+does: slicing each layer's weights and caches out of their stacks and
+stacking the written caches back. A profile of the compiled programs
+then lays each device operation to its layer (the scopes are metadata:
+the computation is the same without them).
 """
 
 from __future__ import annotations
@@ -86,33 +95,37 @@ def encdec_init(key, cfg):
 def encdec_encode(ctx: Ctx, params, cfg, src_tokens=None, frames=None,
                   remat: bool = False):
     """Bidirectional encoder. src_tokens (B,Se) or frames (B,F,d) (audio)."""
-    if frames is not None:
-        x = frames.astype(ctx.compute_dtype)          # stub conv frontend
-    else:
-        x = embed_lookup(params["embedding"], src_tokens, ctx.compute_dtype)
+    with jax.named_scope("embed"):
+        if frames is not None:
+            x = frames.astype(ctx.compute_dtype)      # stub conv frontend
+        else:
+            x = embed_lookup(params["embedding"], src_tokens,
+                             ctx.compute_dtype)
     x = hint(x, "batch", None, None)
     B, Se, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(Se, dtype=jnp.int32), (B, Se))
 
     def body(x, lp):
-        h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
-        y, _ = attn_apply(ctx, lp["attn"], h, positions,
-                          num_heads=cfg.num_heads,
-                          num_kv_heads=cfg.num_kv_heads,
-                          head_dim=cfg.head_dim, causal=False, window=0,
-                          rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                          site="enc.attn")
-        x = x + y
-        h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
-        if cfg.moe is not None:
-            y, _ = moe_mod.moe_apply(ctx, lp["moe"], h, top_k=cfg.moe.top_k,
-                                     capacity_factor=cfg.moe.capacity_factor,
-                                     act=cfg.mlp_act,
-                                     parallel_mode=cfg.moe.parallel_mode,
-                                     dispatch_groups=cfg.moe.dispatch_groups)
-        else:
-            y = mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="enc.ffn")
-        x = x + y
+        with jax.named_scope("enc.attn"):
+            h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
+            y, _ = attn_apply(ctx, lp["attn"], h, positions,
+                              num_heads=cfg.num_heads,
+                              num_kv_heads=cfg.num_kv_heads,
+                              head_dim=cfg.head_dim, causal=False, window=0,
+                              rope_theta=cfg.rope_theta,
+                              norm_eps=cfg.norm_eps, site="enc.attn")
+            x = x + y
+        with jax.named_scope("enc.ffn"):
+            h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
+            if cfg.moe is not None:
+                y, _ = moe_mod.moe_apply(
+                    ctx, lp["moe"], h, top_k=cfg.moe.top_k,
+                    capacity_factor=cfg.moe.capacity_factor,
+                    act=cfg.mlp_act, parallel_mode=cfg.moe.parallel_mode,
+                    dispatch_groups=cfg.moe.dispatch_groups)
+            else:
+                y = mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="enc.ffn")
+            x = x + y
         return hint_pick(x, ("batch", "model", None),
                          ("batch", None, None)), None
 
@@ -123,52 +136,63 @@ def encdec_encode(ctx: Ctx, params, cfg, src_tokens=None, frames=None,
 
 def _dec_layer(ctx, cfg, lp, x, positions, enc_kv, collect_kv):
     """enc_kv = (k, v, enc_positions) precomputed cross K/V."""
-    h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
-    y, kv = attn_apply(ctx, lp["attn"], h, positions,
-                       num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                       head_dim=cfg.head_dim, causal=True, window=0,
-                       rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                       site="dec.attn")
-    x = x + y
-    h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
-    y, _ = attn_apply(ctx, lp["cross"], h, positions,
-                      num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                      head_dim=cfg.head_dim, causal=False, window=0,
-                      kv_override=enc_kv, use_rope=False,
-                      norm_eps=cfg.norm_eps, site="dec.cross")
-    x = x + y
-    h = rms_norm(x, lp["norm3_scale"], cfg.norm_eps)
-    if cfg.moe is not None:
-        y, aux = moe_mod.moe_apply(ctx, lp["moe"], h, top_k=cfg.moe.top_k,
-                                   capacity_factor=cfg.moe.capacity_factor,
-                                   act=cfg.mlp_act,
-                                   parallel_mode=cfg.moe.parallel_mode,
-                                     dispatch_groups=cfg.moe.dispatch_groups)
-    else:
-        y, aux = (mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="dec.ffn"),
-                  jnp.zeros((), jnp.float32))
-    return hint_pick(x + y, ("batch", "model", None),
+    with jax.named_scope("dec.attn"):
+        h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
+        y, kv = attn_apply(ctx, lp["attn"], h, positions,
+                           num_heads=cfg.num_heads,
+                           num_kv_heads=cfg.num_kv_heads,
+                           head_dim=cfg.head_dim, causal=True, window=0,
+                           rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+                           site="dec.attn")
+        x = x + y
+    with jax.named_scope("dec.cross"):
+        h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
+        y, _ = attn_apply(ctx, lp["cross"], h, positions,
+                          num_heads=cfg.num_heads,
+                          num_kv_heads=cfg.num_kv_heads,
+                          head_dim=cfg.head_dim, causal=False, window=0,
+                          kv_override=enc_kv, use_rope=False,
+                          norm_eps=cfg.norm_eps, site="dec.cross")
+        x = x + y
+    with jax.named_scope("dec.ffn"):
+        h = rms_norm(x, lp["norm3_scale"], cfg.norm_eps)
+        if cfg.moe is not None:
+            y, aux = moe_mod.moe_apply(
+                ctx, lp["moe"], h, top_k=cfg.moe.top_k,
+                capacity_factor=cfg.moe.capacity_factor, act=cfg.mlp_act,
+                parallel_mode=cfg.moe.parallel_mode,
+                dispatch_groups=cfg.moe.dispatch_groups)
+        else:
+            y, aux = (mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="dec.ffn"),
+                      jnp.zeros((), jnp.float32))
+        x = x + y
+    return hint_pick(x, ("batch", "model", None),
                      ("batch", None, None)), aux, kv
 
 
 def _cross_kv(ctx, lp, cfg, enc_out):
     """Per-layer cross-attention K/V from encoder output."""
     B, Se, _ = enc_out.shape
-    k = ctx.dot(enc_out, lp["cross"]["wk"], site="dec.cross.kv").reshape(
-        B, Se, cfg.num_kv_heads, cfg.head_dim)
-    v = ctx.dot(enc_out, lp["cross"]["wv"], site="dec.cross.kv").reshape(
-        B, Se, cfg.num_kv_heads, cfg.head_dim)
+    with jax.named_scope("dec.cross.kv"):
+        k = ctx.dot(enc_out, lp["cross"]["wk"], site="dec.cross.kv").reshape(
+            B, Se, cfg.num_kv_heads, cfg.head_dim)
+        v = ctx.dot(enc_out, lp["cross"]["wv"], site="dec.cross.kv").reshape(
+            B, Se, cfg.num_kv_heads, cfg.head_dim)
     return k, v
 
 
 def _head(ctx, params, cfg, x):
-    if cfg.tie_embeddings:
-        w = maybe_dequantize(params["embedding"], ctx.compute_dtype)
-        logits = jnp.einsum("bsd,vd->bsv", x.astype(ctx.compute_dtype), w)
-    else:
-        logits = ctx.dot(x, params["lm_head"], site="head")
-    return hint_pick(logits.astype(jnp.float32),
-                     ("batch", "model", None), ("batch", None, "model"))
+    """The decoder's final norm and the (tied) head."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["decoder"]["norm_f_scale"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            w = maybe_dequantize(params["embedding"], ctx.compute_dtype)
+            logits = jnp.einsum("bsd,vd->bsv", x.astype(ctx.compute_dtype),
+                                w)
+        else:
+            logits = ctx.dot(x, params["lm_head"], site="head")
+        return hint_pick(logits.astype(jnp.float32),
+                         ("batch", "model", None), ("batch", None, "model"))
 
 
 def encdec_forward(ctx: Ctx, params, cfg, tgt_tokens, src_tokens=None,
@@ -177,7 +201,8 @@ def encdec_forward(ctx: Ctx, params, cfg, tgt_tokens, src_tokens=None,
     enc_out = encdec_encode(ctx, params, cfg, src_tokens, frames, remat)
     B, Sd = tgt_tokens.shape
     Se = enc_out.shape[1]
-    x = embed_lookup(params["embedding"], tgt_tokens, ctx.compute_dtype)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embedding"], tgt_tokens, ctx.compute_dtype)
     x = hint(x, "batch", None, None)
     positions = jnp.broadcast_to(jnp.arange(Sd, dtype=jnp.int32), (B, Sd))
     enc_pos = jnp.broadcast_to(jnp.arange(Se, dtype=jnp.int32), (B, Se))
@@ -192,7 +217,6 @@ def encdec_forward(ctx: Ctx, params, cfg, tgt_tokens, src_tokens=None,
     body_fn = jax.checkpoint(body) if remat else body
     (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
                                params["decoder"]["layers"])
-    x = rms_norm(x, params["decoder"]["norm_f_scale"], cfg.norm_eps)
     return _head(ctx, params, cfg, x), aux
 
 
@@ -255,7 +279,8 @@ def encdec_prefill(ctx: Ctx, params, cfg, cache, tgt_tokens, src_tokens=None,
     enc_out = encdec_encode(ctx, params, cfg, src_tokens, frames)
     B, Sd = tgt_tokens.shape
     Se = enc_out.shape[1]
-    x = embed_lookup(params["embedding"], tgt_tokens, ctx.compute_dtype)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embedding"], tgt_tokens, ctx.compute_dtype)
     positions = jnp.broadcast_to(jnp.arange(Sd, dtype=jnp.int32), (B, Sd))
     enc_pos = jnp.broadcast_to(jnp.arange(Se, dtype=jnp.int32), (B, Se))
 
@@ -266,9 +291,9 @@ def encdec_prefill(ctx: Ctx, params, cfg, cache, tgt_tokens, src_tokens=None,
                               (ck, cv, enc_pos), True)
         return (x,), (kv[0], kv[1], ck, cv)
 
-    (x,), (ks, vs, cks, cvs) = jax.lax.scan(
-        body, (x,), params["decoder"]["layers"])
-    x = rms_norm(x, params["decoder"]["norm_f_scale"], cfg.norm_eps)
+    with jax.named_scope("dec.layers"):
+        (x,), (ks, vs, cks, cvs) = jax.lax.scan(
+            body, (x,), params["decoder"]["layers"])
     logits = _head(ctx, params, cfg, x)
 
     lens = lengths if lengths is not None else jnp.full((B,), Sd, jnp.int32)
@@ -316,6 +341,20 @@ def _enc_positions(cache, B: int, Se: int):
     return jnp.where(enc_pos < cross_len[:, None], enc_pos, -1)
 
 
+def _dec_ffn(ctx, cfg, lp, x):
+    """One decode step's norm3 and FFN (dropless experts if MoE)."""
+    h = rms_norm(x, lp["norm3_scale"], cfg.norm_eps)
+    if cfg.moe is not None:
+        y, _ = moe_mod.moe_apply(ctx, lp["moe"], h, top_k=cfg.moe.top_k,
+                                 capacity_factor=cfg.moe.capacity_factor,
+                                 act=cfg.mlp_act,
+                                 parallel_mode=cfg.moe.parallel_mode,
+                                 dropless=True,
+                                 dispatch_groups=cfg.moe.dispatch_groups)
+        return y
+    return mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="dec.ffn")
+
+
 def encdec_decode_step(ctx: Ctx, params, cfg, tokens, cache):
     """One decoder token against self + cross caches. tokens (B,1).
 
@@ -328,7 +367,8 @@ def encdec_decode_step(ctx: Ctx, params, cfg, tokens, cache):
         return encdec_paged_decode_step(ctx, params, cfg, tokens, cache)
     B = tokens.shape[0]
     positions = cache["len"][:, None]
-    x = embed_lookup(params["embedding"], tokens, ctx.compute_dtype)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embedding"], tokens, ctx.compute_dtype)
     quant = "k_codes" in cache
     fp8 = "k_scales" in cache and not quant
     scaled = quant or fp8
@@ -352,50 +392,49 @@ def encdec_decode_step(ctx: Ctx, params, cfg, tokens, cache):
     def body(x, layer_xs):
         if scaled:
             lp, kc, ksc, vc, vsc, ckc, cksc, cvc, cvsc = layer_xs
-            k_dense, v_dense = _dense_kv(kc, ksc), _dense_kv(vc, vsc)
-            ck, cv = _dense_kv(ckc, cksc), _dense_kv(cvc, cvsc)
         else:
-            lp, k_dense, v_dense, ck, cv = layer_xs
-            kc, vc, ksc, vsc = k_dense, v_dense, None, None
-        h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
-        y, k_new, v_new = decode_attn_apply(
-            ctx, lp["attn"], h, positions, k_dense, v_dense, cache["pos"],
-            num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim, window=0, rope_theta=cfg.rope_theta,
-            norm_eps=cfg.norm_eps, site="dec.attn")
-        x = x + y
-        h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
-        y, _ = attn_apply(ctx, lp["cross"], h, positions,
-                          num_heads=cfg.num_heads,
-                          num_kv_heads=cfg.num_kv_heads,
-                          head_dim=cfg.head_dim, causal=False, window=0,
-                          kv_override=(ck, cv, enc_pos), use_rope=False,
-                          norm_eps=cfg.norm_eps, site="dec.cross")
-        x = x + y
-        h = rms_norm(x, lp["norm3_scale"], cfg.norm_eps)
-        if cfg.moe is not None:
-            y, _ = moe_mod.moe_apply(ctx, lp["moe"], h, top_k=cfg.moe.top_k,
-                                     capacity_factor=cfg.moe.capacity_factor,
-                                     act=cfg.mlp_act,
-                                     parallel_mode=cfg.moe.parallel_mode,
-                                     dropless=True,
-                                     dispatch_groups=cfg.moe.dispatch_groups)
-        else:
-            y = mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="dec.ffn")
-        x = x + y
-        if scaled:
-            qfn = _quantize_token_kv if quant else _fp8_token_kv
-            nkc, nks = qfn(k_new)
-            nvc, nvs = qfn(v_new)
-            return x, (_scatter_tokens(kc, nkc, cache["len"]),
-                       _scatter_tokens(ksc, nks, cache["len"]),
-                       _scatter_tokens(vc, nvc, cache["len"]),
-                       _scatter_tokens(vsc, nvs, cache["len"]))
-        return x, (_scatter_tokens(kc, k_new, cache["len"]),
-                   _scatter_tokens(vc, v_new, cache["len"]))
+            lp, kc, vc, ck, cv = layer_xs
+        with jax.named_scope("dec.attn"):
+            if scaled:
+                k_dense, v_dense = _dense_kv(kc, ksc), _dense_kv(vc, vsc)
+            else:
+                k_dense, v_dense = kc, vc
+            h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
+            y, k_new, v_new = decode_attn_apply(
+                ctx, lp["attn"], h, positions, k_dense, v_dense,
+                cache["pos"], num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
+                window=0, rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+                site="dec.attn")
+            x = x + y
+            if scaled:
+                qfn = _quantize_token_kv if quant else _fp8_token_kv
+                nkc, nks = qfn(k_new)
+                nvc, nvs = qfn(v_new)
+                new_kv = (_scatter_tokens(kc, nkc, cache["len"]),
+                          _scatter_tokens(ksc, nks, cache["len"]),
+                          _scatter_tokens(vc, nvc, cache["len"]),
+                          _scatter_tokens(vsc, nvs, cache["len"]))
+            else:
+                new_kv = (_scatter_tokens(kc, k_new, cache["len"]),
+                          _scatter_tokens(vc, v_new, cache["len"]))
+        with jax.named_scope("dec.cross"):
+            if scaled:
+                ck, cv = _dense_kv(ckc, cksc), _dense_kv(cvc, cvsc)
+            h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
+            y, _ = attn_apply(ctx, lp["cross"], h, positions,
+                              num_heads=cfg.num_heads,
+                              num_kv_heads=cfg.num_kv_heads,
+                              head_dim=cfg.head_dim, causal=False, window=0,
+                              kv_override=(ck, cv, enc_pos), use_rope=False,
+                              norm_eps=cfg.norm_eps, site="dec.cross")
+            x = x + y
+        with jax.named_scope("dec.ffn"):
+            x = x + _dec_ffn(ctx, cfg, lp, x)
+        return x, new_kv
 
-    x, new_kv = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["decoder"]["norm_f_scale"], cfg.norm_eps)
+    with jax.named_scope("dec.layers"):
+        x, new_kv = jax.lax.scan(body, x, xs)
     logits = _head(ctx, params, cfg, x)
     new_cache = dict(cache)
     if quant:
@@ -455,15 +494,18 @@ def encdec_paged_decode_step(ctx: Ctx, params, cfg, tokens, cache):
     tables, active = cache["block_tables"], cache["active"]
     B = tokens.shape[0]
     positions = cache["len"][:, None]
-    view_pos, pid, off = paged_view(cache)
-    x = embed_lookup(params["embedding"], tokens, ctx.compute_dtype)
+    with jax.named_scope("dec.attn"):
+        view_pos, pid, off = paged_view(cache)
+        lengths_now = jnp.where(active > 0, cache["len"] + 1, 0)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embedding"], tokens, ctx.compute_dtype)
     quant = "k_codes" in cache
     fp8 = "k_scales" in cache and not quant
     scaled = quant or fp8
     Se = (cache["cross_k_codes"] if quant else cache["cross_k"]).shape[2]
-    enc_pos = _enc_positions(cache, B, Se)
+    with jax.named_scope("dec.cross"):
+        enc_pos = _enc_positions(cache, B, Se)
     use_kernel = ctx.paged_attn_impl == "kernel"
-    lengths_now = jnp.where(active > 0, cache["len"] + 1, 0)
 
     if quant:
         xs = (params["decoder"]["layers"], cache["k_codes"],
@@ -483,40 +525,35 @@ def encdec_paged_decode_step(ctx: Ctx, params, cfg, tokens, cache):
         if scaled:
             lp, *leaves = layer_xs[:5]
             ckc, cksc, cvc, cvsc = layer_xs[5:]
-            ck, cv = _dense_kv(ckc, cksc), _dense_kv(cvc, cvsc)
         else:
             lp, *leaves = layer_xs[:3]
             ck, cv = layer_xs[3:]
-        h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
-        y, new_leaves = paged_attn(
-            ctx, lp["attn"], h, positions, leaves, view_pos, pid, off,
-            lengths_now, tables, use_kernel=use_kernel,
-            num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim, window=0, rope_theta=cfg.rope_theta,
-            norm_eps=cfg.norm_eps, site="dec.attn")
-        x = x + y
-        h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
-        y, _ = attn_apply(ctx, lp["cross"], h, positions,
-                          num_heads=cfg.num_heads,
-                          num_kv_heads=cfg.num_kv_heads,
-                          head_dim=cfg.head_dim, causal=False, window=0,
-                          kv_override=(ck, cv, enc_pos), use_rope=False,
-                          norm_eps=cfg.norm_eps, site="dec.cross")
-        x = x + y
-        h = rms_norm(x, lp["norm3_scale"], cfg.norm_eps)
-        if cfg.moe is not None:
-            y, _ = moe_mod.moe_apply(ctx, lp["moe"], h, top_k=cfg.moe.top_k,
-                                     capacity_factor=cfg.moe.capacity_factor,
-                                     act=cfg.mlp_act,
-                                     parallel_mode=cfg.moe.parallel_mode,
-                                     dropless=True,
-                                     dispatch_groups=cfg.moe.dispatch_groups)
-        else:
-            y = mlp(ctx, lp["mlp"], h, cfg.mlp_act, site="dec.ffn")
-        return x + y, new_leaves
+        with jax.named_scope("dec.attn"):
+            h = rms_norm(x, lp["norm1_scale"], cfg.norm_eps)
+            y, new_leaves = paged_attn(
+                ctx, lp["attn"], h, positions, leaves, view_pos, pid, off,
+                lengths_now, tables, use_kernel=use_kernel,
+                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim, window=0, rope_theta=cfg.rope_theta,
+                norm_eps=cfg.norm_eps, site="dec.attn")
+            x = x + y
+        with jax.named_scope("dec.cross"):
+            if scaled:
+                ck, cv = _dense_kv(ckc, cksc), _dense_kv(cvc, cvsc)
+            h = rms_norm(x, lp["norm2_scale"], cfg.norm_eps)
+            y, _ = attn_apply(ctx, lp["cross"], h, positions,
+                              num_heads=cfg.num_heads,
+                              num_kv_heads=cfg.num_kv_heads,
+                              head_dim=cfg.head_dim, causal=False, window=0,
+                              kv_override=(ck, cv, enc_pos), use_rope=False,
+                              norm_eps=cfg.norm_eps, site="dec.cross")
+            x = x + y
+        with jax.named_scope("dec.ffn"):
+            x = x + _dec_ffn(ctx, cfg, lp, x)
+        return x, new_leaves
 
-    x, new_kv = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["decoder"]["norm_f_scale"], cfg.norm_eps)
+    with jax.named_scope("dec.layers"):
+        x, new_kv = jax.lax.scan(body, x, xs)
     logits = _head(ctx, params, cfg, x)
     new_cache = dict(cache)
     if quant:

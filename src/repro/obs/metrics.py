@@ -13,10 +13,12 @@ other call sites import it.
 
 ``Histogram`` is a fixed log-bucket histogram: O(1) memory regardless
 of sample count, mergeable across engines/replicas, and cheap enough to
-record into from the serving engine's retire path unconditionally. Its
-``percentile`` returns the *upper edge* of the bucket holding the
-nearest-rank sample (the standard Prometheus-style bound; exact values
-are not retained).
+record into from the serving engine's retire path unconditionally.
+Buckets grow by a quarter octave (2^(1/4)), and ``percentile`` returns
+the geometric middle of the bucket holding the nearest-rank sample, so
+it lies within 2^(1/8) (under 9.1%) of that sample; exact values are
+not retained. (An upper edge of octave buckets, as before, read up to
+twice the sample.)
 """
 
 from __future__ import annotations
@@ -65,20 +67,26 @@ def _bounds(lo: float, growth: float, n_buckets: int) -> Tuple[float, ...]:
     return b
 
 
+# quarter-octave buckets from 1 microsecond (samples in ms) to 2^29 us,
+# about 9 minutes: the range the octave buckets covered before
+_GROWTH = 2.0 ** 0.25
+_N_BUCKETS = 117
+
+
 class Histogram:
     """Fixed log-bucket histogram with merge and nearest-rank quantiles.
 
     Bucket ``i`` counts samples in ``(bounds[i-1], bounds[i]]``; bucket 0
     additionally absorbs everything ``<= lo`` (including zeros), and one
     overflow bucket absorbs samples above the last bound. Defaults cover
-    1 microsecond to ~18 minutes when samples are milliseconds.
+    1 microsecond to ~9 minutes when samples are milliseconds.
     """
 
     __slots__ = ("lo", "growth", "n_buckets", "bounds", "counts",
                  "overflow", "count", "total")
 
-    def __init__(self, lo: float = 1e-3, growth: float = 2.0,
-                 n_buckets: int = 30):
+    def __init__(self, lo: float = 1e-3, growth: float = _GROWTH,
+                 n_buckets: int = _N_BUCKETS):
         self.lo = float(lo)
         self.growth = float(growth)
         self.n_buckets = int(n_buckets)
@@ -125,7 +133,9 @@ class Histogram:
         return self.total / self.count if self.count else 0.0
 
     def percentile(self, q: float) -> float:
-        """Upper bucket edge holding the nearest-rank sample (0.0 if empty)."""
+        """Geometric middle of the bucket holding the nearest-rank sample
+        (``bounds[i] / sqrt(growth)``; 0.0 if empty). A sample in the
+        overflow bucket reads the last bound."""
         if self.count == 0:
             return 0.0
         if not 0.0 <= q <= 100.0:
@@ -136,7 +146,7 @@ class Histogram:
         for i, c in enumerate(self.counts):
             seen += c
             if seen > rank:
-                return self.bounds[i]
+                return self.bounds[i] / self.growth ** 0.5
         return self.bounds[-1]  # nearest-rank sample sits in overflow
 
     def as_dict(self) -> Dict[str, object]:

@@ -19,7 +19,10 @@ Design constraints, in order:
 
 Track (``tid``) convention: tid 0 (:data:`SCHED_TID`) is the scheduler
 track carrying ``round`` spans with ``admit``/``dispatch``/``sync``/
-``walk`` phase events; each request gets tid ``rid + 1`` carrying its
+``walk`` phase events and nothing else; tid -1 (:data:`ADMIT_TID`,
+"admission") carries the admission's sub-spans (:data:`ADMIT_SPANS`,
+each inside an ``admit`` phase); each request gets tid ``rid + 1``
+carrying its
 lifecycle span (``request`` wrapping ``queued``, a ``prefill`` complete
 event, ``decode-round``/``verify``/``preempted``/``resumed`` instants,
 and a ``retired`` instant with the finish reason). A ``resume`` flow
@@ -30,21 +33,65 @@ continuity arrow across the gap.
 
 Export is the Chrome ``trace_event`` JSON array format — load the file
 in ``chrome://tracing`` or https://ui.perfetto.dev.
+
+Mirrored into the profiler: the scheduler phases and the admission
+sub-spans are recorded through :meth:`Tracer.span`, which also holds a
+``jax.profiler.TraceAnnotation`` of the same name open around the block.
+Under a running JAX profiler they then sit on the host plane of the
+profile, on the same clock as the device's operations, nested as they
+ran (``admit`` > ``admit.prefill``; the device's ``jit__prefill_paged``
+module right after it). The jitted programs name their layers with
+``jax.named_scope``: ``embed``, ``enc.attn``, ``enc.ffn``,
+``dec.cross.kv``, ``dec.attn``, ``dec.cross``, ``dec.ffn`` and ``head``
+(the ``site`` names ``Ctx.dot`` uses), ``dec.layers`` (the decoder's
+layer loop itself) and ``sampler``. The compiler keeps them in each
+instruction's ``op_name`` metadata, so a device operation can be laid
+to its layer through the compiled program's text (``bench/layers.py``
+does). To capture a profile::
+
+    engine = deploy(..., trace=TraceConfig()).engine
+    jax.profiler.start_trace(prof_dir)    # or `with jax.profiler.trace(...)`
+    ...  # serve
+    jax.profiler.stop_trace()
+
+then open ``<prof_dir>/plugins/profile/<time>/*.xplane.pb`` in
+TensorBoard's profile plugin, or in https://ui.perfetto.dev (pass
+``create_perfetto_trace=True`` for a ``perfetto_trace.json.gz``).
+Without a running profiler an annotation costs one check.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Deque, Dict, List, Optional, Tuple,
+                    TYPE_CHECKING)
 
-__all__ = ["PHASES", "SCHED_TID", "TraceConfig", "TraceEvent", "Tracer"]
+from jax.profiler import TraceAnnotation
+
+if TYPE_CHECKING:
+    from .metrics import Histogram
+
+__all__ = ["ADMIT_SPANS", "ADMIT_TID", "PHASES", "SCHED_TID", "TraceConfig",
+           "TraceEvent", "Tracer"]
 
 # Scheduler round phases, in the order they run inside a round.
 PHASES: Tuple[str, ...] = ("admit", "dispatch", "sync", "walk")
 
+# The admission's sub-spans, in the order they run inside ``admit``:
+# fault tick, deadline expiry, then per admission group: taking it off
+# the queue, building its prefill inputs, its page chains, the prefill
+# call, the first-token sync, installing the slots' state, and
+# delivering the first tokens.
+ADMIT_SPANS: Tuple[str, ...] = (
+    "admit.faults", "admit.expire", "admit.group", "admit.inputs",
+    "admit.pages", "admit.prefill", "admit.first_sync", "admit.install",
+    "admit.emit")
+
 SCHED_TID = 0
+ADMIT_TID = -1
 _PID = 1  # single-process engine; one pid for the whole trace
 
 
@@ -103,7 +150,8 @@ class Tracer:
         self.dropped = 0
         self._floor_us = float("-inf")
         self._next_flow = 0
-        self._track_names: Dict[int, str] = {SCHED_TID: "scheduler"}
+        self._track_names: Dict[int, str] = {SCHED_TID: "scheduler",
+                                             ADMIT_TID: "admission"}
 
     def __len__(self) -> int:
         return len(self.events)
@@ -145,6 +193,19 @@ class Tracer:
         self._record(TraceEvent("X", name, ts_s * 1e6, tid,
                                 dur_us=max(dur_s, 0.0) * 1e6,
                                 args=args or None))
+
+    def span(self, tid: int, name: str, clock: Callable[[], float],
+             hist: Optional["Histogram"] = None, **args: Any) -> "_Span":
+        """Context manager timing one block: on exit it records the
+        block as a complete (``X``) event on ``tid``, its duration from
+        ``time.perf_counter`` and its start anchored on ``clock()`` (the
+        engine clock, which a fault plan may skew), and records the
+        duration in ms into ``hist`` if given. Meanwhile it holds a
+        ``jax.profiler.TraceAnnotation`` of the same name (with
+        ``args``) open, so a running profiler shows the block on its
+        host plane. The caller decides whether to trace at all: an
+        untraced engine never calls this."""
+        return _Span(self, tid, name, clock, hist, args)
 
     def flow_start(self, tid: int, name: str, ts_s: float,
                    **args: Any) -> int:
@@ -313,3 +374,31 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
             f.write("\n")
+
+
+class _Span:
+    """One :meth:`Tracer.span` block (a class, not a generator, to keep
+    the traced round loop's cost per span to two method calls)."""
+
+    __slots__ = ("tracer", "tid", "name", "clock", "hist", "args", "t0",
+                 "annotation")
+
+    def __init__(self, tracer: Tracer, tid: int, name: str,
+                 clock: Callable[[], float], hist: Optional["Histogram"],
+                 args: Dict[str, Any]):
+        self.tracer, self.tid, self.name = tracer, tid, name
+        self.clock, self.hist, self.args = clock, hist, args
+
+    def __enter__(self) -> "_Span":
+        self.annotation = TraceAnnotation(self.name, **self.args)
+        self.annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dur = time.perf_counter() - self.t0
+        start = self.clock() - dur      # read at once: spans stay nested
+        self.annotation.__exit__(*exc)
+        if self.hist is not None:
+            self.hist.record(dur * 1e3)
+        self.tracer.complete(self.tid, self.name, start, dur, **self.args)

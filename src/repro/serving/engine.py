@@ -140,6 +140,7 @@ single-shot engine so pre-request-API callers stay green.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -151,7 +152,8 @@ import numpy as np
 
 from ..models.api import decode_block
 from ..models.layers import Ctx
-from ..obs import PHASES, SCHED_TID, Histogram, TraceConfig, Tracer
+from ..obs import (ADMIT_TID, PHASES, SCHED_TID, Histogram, TraceConfig,
+                   Tracer)
 from ..parallel import (cache_shardings, paged_pool_shardings,
                         param_shardings, set_mesh)
 from ..obs.metrics import render_prometheus
@@ -169,6 +171,8 @@ __all__ = ["ServeEngine", "greedy_generate", "translate"]
 # the last-real-token index is not lengths-derived; ssm/hybrid recurrent
 # states would absorb pad tokens)
 _PAD_SAFE = ("dense", "moe", "encdec", "audio")
+# what ServeEngine._span hands an untraced engine: a shared no-op block
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -337,10 +341,10 @@ class ServeEngine:
         # ttft/tpot histograms record once per retirement — never in
         # the round loop — so latency percentiles in metrics() are free
         # and exist even when tracing is off. Phase timing fills only
-        # under tracing (it needs extra perf_counter reads per phase).
+        # under tracing (it needs extra perf_counter reads per phase);
+        # a phase's total ms is its histogram's sum.
         self._ttft_hist = Histogram()
         self._tpot_hist = Histogram()
-        self._phase_ms: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self._phase_hist: Dict[str, Histogram] = {p: Histogram()
                                                   for p in PHASES}
         # -- fault tolerance ------------------------------------------
@@ -359,6 +363,7 @@ class ServeEngine:
         self._preempted: Dict[int, list] = {}       # rid -> stashed tokens
         self._preempt_counts: Dict[int, int] = {}   # rid -> eviction count
         self._flow_ids: Dict[int, int] = {}         # rid -> open trace flow
+        self._queued_at: Dict[int, float] = {}      # rid -> queued since
         self._disp_len: Dict[int, int] = {}  # slot -> dispatched positions
         self._no_poison = jnp.full((slots,), -1, jnp.int32)
         self._preemptions = 0
@@ -561,6 +566,7 @@ class ServeEngine:
         arrival = self._now()
         self._stats[request.id] = RequestStats(
             arrival_s=arrival, prompt_len=prompt_len)
+        self._queued_at[request.id] = arrival
         if self.trace is not None:
             tid = request.id + 1
             self.trace.name_track(tid, f"req {request.id}")
@@ -763,17 +769,20 @@ class ServeEngine:
         (FaultPlan deadline tests advance time without sleeping)."""
         return time.perf_counter() + self._skew_s
 
-    def _phase_done(self, phase: str, t0: float, **args) -> None:
-        """Close one scheduler phase (tracing enabled only): accumulate
-        its wall duration and emit the complete event. Durations come
-        from raw perf_counter deltas so a fault-injected skew jump
-        inside a phase (faults tick during "admit") cannot inflate it;
-        the event timestamp is anchored on the engine clock so the
-        trace timeline still shows the skew."""
-        dur = time.perf_counter() - t0
-        self._phase_ms[phase] += dur * 1e3
-        self._phase_hist[phase].record(dur * 1e3)
-        self.trace.complete(SCHED_TID, phase, self._now() - dur, dur, **args)
+    def _span(self, name: str, tid: int = SCHED_TID, **args):
+        """The block of one scheduler phase (tid 0, its duration fed to
+        the phase's histogram) or admission sub-span (``ADMIT_TID``) as
+        a ``Tracer.span``: a complete event plus a profiler annotation
+        of the same name. Durations come from raw perf_counter deltas so
+        a fault-injected skew jump inside a phase (faults tick during
+        "admit") cannot inflate it; the event timestamp is anchored on
+        the engine clock so the trace timeline still shows the skew. An
+        untraced engine gets a shared no-op block: no clock read, no
+        call into the tracer or the profiler."""
+        if self.trace is None:
+            return _NO_SPAN
+        return self.trace.span(tid, name, self._now,
+                               self._phase_hist.get(name), **args)
 
     def _round_begin(self) -> None:
         self._round_no += 1
@@ -788,14 +797,13 @@ class ServeEngine:
         deadlines, then admit from the queue. Runs on no-op rounds too,
         so transient faults clear and expired queued requests drain
         even when nothing is decoding."""
-        tr = self.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
-        if self.faults is not None:
-            self.faults.on_round(self)
-        self._expire_deadlines()
-        self._admit_pending()
-        if tr is not None:
-            self._phase_done("admit", t0)
+        with self._span("admit"):
+            if self.faults is not None:
+                with self._span("admit.faults", ADMIT_TID):
+                    self.faults.on_round(self)
+            with self._span("admit.expire", ADMIT_TID):
+                self._expire_deadlines()
+            self._admit_pending()
 
     def _deadline_passed(self, request: Request, now: float) -> bool:
         dl = request.params.deadline_ms
@@ -831,6 +839,7 @@ class ServeEngine:
         self._preempt_counts.pop(r.id, None)
         fid = self._flow_ids.pop(r.id, None)
         st.finished_s = self._now()
+        st.queued_s += st.finished_s - self._queued_at.pop(r.id)
         if st.first_token_s == 0.0:
             st.first_token_s = st.finished_s
         st.new_tokens = len(toks)
@@ -904,35 +913,29 @@ class ServeEngine:
         """The legacy horizon=1 path: one fused decode+sample dispatch,
         one host sync per token."""
         tr = self.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
-        self._grow_chains(1)
-        self._decode_steps += 1
-        self._active_slot_steps += sum(s.active for s in self.slots)
-        if self.paged:
-            self._page_slot_steps += self.allocator.pages_in_use
-        self.cache, nxt = self._step_fn(
-            self.params, self.cur, self.cache, self._temps,
-            self._top_ks, self._top_ps, self._keys, self._offsets,
-            self._poison_arr(1))
-        self._note_dispatched(1)
-        self.cur = nxt[:, None]
-        self._offsets = self._offsets + 1
-        if tr is not None:
-            self._phase_done("dispatch", t0, K=1)
-            t0 = time.perf_counter()
-        self._decode_syncs += 1
-        nxt_host = np.asarray(nxt)          # one sync per token
-        if tr is not None:
-            self._phase_done("sync", t0, K=1)
-            t0 = time.perf_counter()
-        for s in self.slots:
-            if s.active:
-                if tr is not None:
-                    tr.instant(s.request.id + 1, "decode-round",
-                               self._now(), planned=1)
-                self._emit(s, int(nxt_host[s.id]))
-        if tr is not None:
-            self._phase_done("walk", t0)
+        with self._span("dispatch", K=1):
+            self._grow_chains(1)
+            self._decode_steps += 1
+            self._active_slot_steps += sum(s.active for s in self.slots)
+            if self.paged:
+                self._page_slot_steps += self.allocator.pages_in_use
+            self.cache, nxt = self._step_fn(
+                self.params, self.cur, self.cache, self._temps,
+                self._top_ks, self._top_ps, self._keys, self._offsets,
+                self._poison_arr(1))
+            self._note_dispatched(1)
+            self.cur = nxt[:, None]
+            self._offsets = self._offsets + 1
+        with self._span("sync", K=1):
+            self._decode_syncs += 1
+            nxt_host = np.asarray(nxt)          # one sync per token
+        with self._span("walk"):
+            for s in self.slots:
+                if s.active:
+                    if tr is not None:
+                        tr.instant(s.request.id + 1, "decode-round",
+                                   self._now(), planned=1)
+                    self._emit(s, int(nxt_host[s.id]))
 
     def _dispatch_horizon(self, K: int, carry=None):
         """Dispatch one K-step fused horizon WITHOUT syncing its block.
@@ -955,37 +958,35 @@ class ServeEngine:
         _grow_chains), so block tables are static across the scan
         whichever allocation mode is live.
         """
-        tr = self.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
-        self._grow_chains(K)
-        self._decode_steps += K
-        if self.paged:
-            self._page_slot_steps += K * self.allocator.pages_in_use
-        fn = self._horizon_fns.get(K)
-        if fn is None:
-            fn = self._horizon_fns[K] = self._make_horizon_fn(K)
-        alive_h, rem_h, eos = self._scan_masks()
-        if carry is None:
-            alive, rem = alive_h, rem_h
-        else:
-            alive_c, rem_c = carry
-            fresh = np.zeros((self.n_slots,), bool)
-            for sid in self._dirty_slots:
-                fresh[sid] = True
-            fresh = jnp.asarray(fresh)
-            alive = jnp.where(fresh, alive_h, jnp.minimum(alive_c, alive_h))
-            rem = jnp.where(fresh, rem_h, rem_c)
-        self._dirty_slots.clear()
-        # dispatch-time occupancy snapshot: which request generation
-        # each slot row of this block belongs to (see _walk_block)
-        seqs = tuple(s.seq if s.active else -1 for s in self.slots)
-        self.cache, self.cur, self._offsets, alive_o, rem_o, block = fn(
-            self.params, self.cur, self.cache, self._temps, self._top_ks,
-            self._top_ps, self._keys, self._offsets, alive, rem, eos,
-            self._poison_arr(K))
-        self._note_dispatched(K)
-        if tr is not None:
-            self._phase_done("dispatch", t0, K=K)
+        with self._span("dispatch", K=K):
+            self._grow_chains(K)
+            self._decode_steps += K
+            if self.paged:
+                self._page_slot_steps += K * self.allocator.pages_in_use
+            fn = self._horizon_fns.get(K)
+            if fn is None:
+                fn = self._horizon_fns[K] = self._make_horizon_fn(K)
+            alive_h, rem_h, eos = self._scan_masks()
+            if carry is None:
+                alive, rem = alive_h, rem_h
+            else:
+                alive_c, rem_c = carry
+                fresh = np.zeros((self.n_slots,), bool)
+                for sid in self._dirty_slots:
+                    fresh[sid] = True
+                fresh = jnp.asarray(fresh)
+                alive = jnp.where(fresh, alive_h,
+                                  jnp.minimum(alive_c, alive_h))
+                rem = jnp.where(fresh, rem_h, rem_c)
+            self._dirty_slots.clear()
+            # dispatch-time occupancy snapshot: which request generation
+            # each slot row of this block belongs to (see _walk_block)
+            seqs = tuple(s.seq if s.active else -1 for s in self.slots)
+            self.cache, self.cur, self._offsets, alive_o, rem_o, block = fn(
+                self.params, self.cur, self.cache, self._temps,
+                self._top_ks, self._top_ps, self._keys, self._offsets,
+                alive, rem, eos, self._poison_arr(K))
+            self._note_dispatched(K)
         return alive_o, rem_o, block, K, seqs
 
     def _walk_block(self, block, K: int, seqs=None) -> None:
@@ -1009,25 +1010,21 @@ class ServeEngine:
         if not eligible:
             return
         tr = self.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
-        self._decode_syncs += 1
-        blk = np.asarray(block)             # one sync per horizon
-        if tr is not None:
-            self._phase_done("sync", t0, K=K)
-            t0 = time.perf_counter()
-        for s in eligible:
-            if not s.active:    # retired by a groupmate's callback mid-walk
-                continue
-            if tr is not None:
-                tr.instant(s.request.id + 1, "decode-round", self._now(),
-                           planned=K)
-            for t in range(K):              # walk until retirement
-                self._active_slot_steps += 1
-                self._emit(s, int(blk[t, s.id]))
-                if not s.active:
-                    break
-        if tr is not None:
-            self._phase_done("walk", t0)
+        with self._span("sync", K=K):
+            self._decode_syncs += 1
+            blk = np.asarray(block)             # one sync per horizon
+        with self._span("walk"):
+            for s in eligible:
+                if not s.active:    # retired by a groupmate's callback
+                    continue
+                if tr is not None:
+                    tr.instant(s.request.id + 1, "decode-round",
+                               self._now(), planned=K)
+                for t in range(K):              # walk until retirement
+                    self._active_slot_steps += 1
+                    self._emit(s, int(blk[t, s.id]))
+                    if not s.active:
+                        break
 
     def _ahead_horizon(self, K_cfg: int, Kd: int) -> int:
         """Length of the next scan to dispatch before walking the
@@ -1181,10 +1178,10 @@ class ServeEngine:
             ttft_p95_ms=round(self._ttft_hist.percentile(95.0), 4),
             tpot_p50_ms=round(self._tpot_hist.percentile(50.0), 4),
             tpot_p95_ms=round(self._tpot_hist.percentile(95.0), 4),
-            phase_admit_ms=round(self._phase_ms["admit"], 4),
-            phase_dispatch_ms=round(self._phase_ms["dispatch"], 4),
-            phase_sync_ms=round(self._phase_ms["sync"], 4),
-            phase_walk_ms=round(self._phase_ms["walk"], 4),
+            phase_admit_ms=round(self._phase_hist["admit"].total, 4),
+            phase_dispatch_ms=round(self._phase_hist["dispatch"].total, 4),
+            phase_sync_ms=round(self._phase_hist["sync"].total, 4),
+            phase_walk_ms=round(self._phase_hist["walk"].total, 4),
             kv_cache_bytes=self.kv_cache_bytes,
             prefill_compiles=self.prefill_compiles)
 
@@ -1229,7 +1226,6 @@ class ServeEngine:
         self._slot_errors = 0
         self._ttft_hist.reset()
         self._tpot_hist.reset()
-        self._phase_ms = dict.fromkeys(PHASES, 0.0)
         for h in self._phase_hist.values():
             h.reset()
 
@@ -1533,64 +1529,59 @@ class ServeEngine:
         first divergence (1..K tokens per live slot)."""
         draft = self.draft
         tr = self.trace
-        t0 = time.perf_counter() if tr is not None else 0.0
         max_rem = max(s.request.params.max_new_tokens - len(s.tokens)
                       for s in self.slots if s.active)
         K = max(1, min(draft.lookahead, self._bucket(max_rem)))
-        self._decode_steps += K
-        if self.paged:
-            self._page_slot_steps += K * self.allocator.pages_in_use
-        dfn = self._draft_fns.get(K)
-        if dfn is None:
-            dfn = self._draft_fns[K] = self._make_horizon_fn(
-                K, ctx=draft.ctx)
-        vfn = self._verify_fns.get(K)
-        if vfn is None:
-            vfn = self._verify_fns[K] = self._make_verify_fn(K)
-        alive, _, _ = self._scan_masks()
-        # the draft scan must not retire anyone — acceptance is the
-        # verify pass's call: no EOS ids, budget that outlasts the scan
-        rem = (K + 1) * alive
-        self.draft_cache, _, _, _, _, block = dfn(
-            draft.params, self.cur, self.draft_cache, self._z_f,
-            self._z_i, self._o_f, self._z_keys, self._z_i, alive, rem,
-            self._no_eos, self._no_poison)
-        self.cache, self.draft_cache, out, n_emit, acc, self.cur = vfn(
-            self.params, self.cur, self.cache, self.draft_cache, block,
-            alive)
-        self._verify_calls += 1
-        if tr is not None:
-            self._phase_done("dispatch", t0, K=K, spec=1)
-            t0 = time.perf_counter()
-        self._decode_syncs += 1
-        blk = np.asarray(out)               # one sync per round
-        n_emit = np.asarray(n_emit)
-        acc = np.asarray(acc)
-        if tr is not None:
-            self._phase_done("sync", t0, K=K)
-            t0 = time.perf_counter()
-        for s in self.slots:
-            if not s.active:
-                continue
-            a = int(acc[s.id])
-            st = self._stats[s.request.id]
-            st.drafted += K
-            st.accepted += a
-            st.rejected += K - a
-            self._drafted += K
-            self._accepted += a
-            self._rejected += K - a
-            if tr is not None:
-                tr.instant(s.request.id + 1, "verify", self._now(),
-                           drafted=K, accepted=a,
-                           emitted=int(n_emit[s.id]))
-            for t in range(int(n_emit[s.id])):
-                self._active_slot_steps += 1
-                self._emit(s, int(blk[t, s.id]))
+        with self._span("dispatch", K=K, spec=1):
+            self._decode_steps += K
+            if self.paged:
+                self._page_slot_steps += K * self.allocator.pages_in_use
+            dfn = self._draft_fns.get(K)
+            if dfn is None:
+                dfn = self._draft_fns[K] = self._make_horizon_fn(
+                    K, ctx=draft.ctx)
+            vfn = self._verify_fns.get(K)
+            if vfn is None:
+                vfn = self._verify_fns[K] = self._make_verify_fn(K)
+            alive, _, _ = self._scan_masks()
+            # the draft scan must not retire anyone — acceptance is the
+            # verify pass's call: no EOS ids, budget that outlasts the
+            # scan
+            rem = (K + 1) * alive
+            self.draft_cache, _, _, _, _, block = dfn(
+                draft.params, self.cur, self.draft_cache, self._z_f,
+                self._z_i, self._o_f, self._z_keys, self._z_i, alive, rem,
+                self._no_eos, self._no_poison)
+            self.cache, self.draft_cache, out, n_emit, acc, self.cur = vfn(
+                self.params, self.cur, self.cache, self.draft_cache, block,
+                alive)
+            self._verify_calls += 1
+        with self._span("sync", K=K):
+            self._decode_syncs += 1
+            blk = np.asarray(out)               # one sync per round
+            n_emit = np.asarray(n_emit)
+            acc = np.asarray(acc)
+        with self._span("walk"):
+            for s in self.slots:
                 if not s.active:
-                    break
-        if tr is not None:
-            self._phase_done("walk", t0)
+                    continue
+                a = int(acc[s.id])
+                st = self._stats[s.request.id]
+                st.drafted += K
+                st.accepted += a
+                st.rejected += K - a
+                self._drafted += K
+                self._accepted += a
+                self._rejected += K - a
+                if tr is not None:
+                    tr.instant(s.request.id + 1, "verify", self._now(),
+                               drafted=K, accepted=a,
+                               emitted=int(n_emit[s.id]))
+                for t in range(int(n_emit[s.id])):
+                    self._active_slot_steps += 1
+                    self._emit(s, int(blk[t, s.id]))
+                    if not s.active:
+                        break
 
     def _bucket(self, n: int) -> int:
         """Smallest power-of-two >= n, capped at max_len."""
@@ -1705,8 +1696,9 @@ class ServeEngine:
         if n > self.preempt_limit:
             self._retire(s, "preempted_limit")
             return
+        now = self._now()
+        self._queued_at[r.id] = now
         if self.trace is not None:
-            now = self._now()
             self.trace.begin(r.id + 1, "queued", now)
             # link the two slot residencies: flow_end fires at the
             # resume (or at retirement, if the stash dies queued), so
@@ -1746,7 +1738,8 @@ class ServeEngine:
                 self._admit(self._queue.popleft())
             return
         while self._queue:
-            group = self._take_group()
+            with self._span("admit.group", ADMIT_TID):
+                group = self._take_group()
             if not group:
                 break
             self._admit_group(group)
@@ -1852,47 +1845,54 @@ class ServeEngine:
         n = len(group)
         free = [s.id for s in self.slots if not s.active][:n]
         tr = self.trace
+        t_adm = self._now()
+        self._stamp_admitted(group, t_adm)
         if tr is not None:
-            t_adm = self._now()
             for r in group:
                 tr.end(r.id + 1, "queued", t_adm)
             p0 = time.perf_counter()
-        toks = [self._feed_tokens(r) for r in group]
-        true_lens = [t.shape[1] for t in toks]
-        inputs = self._group_inputs(toks, [r.inputs for r in group])
-        chains = []
-        rows = np.zeros((n, self.max_pages), np.int32)  # 0 = trash page
-        for i, r in enumerate(group):
-            chain = self.allocator.alloc_chain(
-                pages_needed(true_lens[i], self.page_size)
-                if self.on_demand else self._arm_pages(r))
-            chains.append(chain)
-            rows[i, :len(chain)] = chain
-        dchains = []
-        if self.draft is not None:
-            drows = np.zeros((n, self.max_pages), np.int32)
+        with self._span("admit.inputs", ADMIT_TID):
+            toks = [self._feed_tokens(r) for r in group]
+            true_lens = [t.shape[1] for t in toks]
+            inputs = self._group_inputs(toks, [r.inputs for r in group])
+            keys = jnp.stack(
+                [jax.random.PRNGKey(r.params.seed) for r in group])
+        with self._span("admit.pages", ADMIT_TID):
+            chains = []
+            rows = np.zeros((n, self.max_pages), np.int32)  # 0 = trash
             for i, r in enumerate(group):
-                dchain = self.allocator.alloc_chain(self._arm_pages(r))
-                dchains.append(dchain)
-                drows[i, :len(dchain)] = dchain
-        keys = jnp.stack(
-            [jax.random.PRNGKey(r.params.seed) for r in group])
-        self.cache, first = self._prefill_paged_fn(
-            self.params, inputs, jnp.asarray(true_lens, jnp.int32),
-            jnp.asarray(free, jnp.int32), jnp.asarray(rows), self.cache,
-            jnp.asarray([r.params.temperature for r in group], jnp.float32),
-            jnp.asarray([r.params.top_k for r in group], jnp.int32),
-            jnp.asarray([r.params.top_p for r in group], jnp.float32),
-            keys)
-        if self.draft is not None:
-            self.draft_cache = self._draft_prefill_paged_fn(
-                self.draft.params, inputs,
-                jnp.asarray(true_lens, jnp.int32),
-                jnp.asarray(free, jnp.int32), jnp.asarray(drows),
-                self.draft_cache)
-        self.prefill_shapes.add(
-            tuple(sorted((k, tuple(v.shape)) for k, v in inputs.items())))
-        first = np.asarray(first)
+                chain = self.allocator.alloc_chain(
+                    pages_needed(true_lens[i], self.page_size)
+                    if self.on_demand else self._arm_pages(r))
+                chains.append(chain)
+                rows[i, :len(chain)] = chain
+            dchains = []
+            if self.draft is not None:
+                drows = np.zeros((n, self.max_pages), np.int32)
+                for i, r in enumerate(group):
+                    dchain = self.allocator.alloc_chain(self._arm_pages(r))
+                    dchains.append(dchain)
+                    drows[i, :len(dchain)] = dchain
+        with self._span("admit.prefill", ADMIT_TID, group=n):
+            self.cache, first = self._prefill_paged_fn(
+                self.params, inputs, jnp.asarray(true_lens, jnp.int32),
+                jnp.asarray(free, jnp.int32), jnp.asarray(rows), self.cache,
+                jnp.asarray([r.params.temperature for r in group],
+                            jnp.float32),
+                jnp.asarray([r.params.top_k for r in group], jnp.int32),
+                jnp.asarray([r.params.top_p for r in group], jnp.float32),
+                keys)
+            if self.draft is not None:
+                self.draft_cache = self._draft_prefill_paged_fn(
+                    self.draft.params, inputs,
+                    jnp.asarray(true_lens, jnp.int32),
+                    jnp.asarray(free, jnp.int32), jnp.asarray(drows),
+                    self.draft_cache)
+            self.prefill_shapes.add(
+                tuple(sorted((k, tuple(v.shape))
+                             for k, v in inputs.items())))
+        with self._span("admit.first_sync", ADMIT_TID):
+            first = np.asarray(first)
         now = self._now()
         if tr is not None:
             # one batched prefill covers the group; each member gets the
@@ -1901,56 +1901,67 @@ class ServeEngine:
             for r in group:
                 tr.complete(r.id + 1, "prefill", now - p_dur, p_dur,
                             group=n)
-        admitted = []
-        for i, (r, sid) in enumerate(zip(group, free)):
-            s = self.slots[sid]
-            sp = r.params
-            stash = self._preempted.pop(r.id, None)
-            if stash:
-                # resume: the replay prefill's sampled token is
-                # discarded — the pending decode token is the last one
-                # emitted before eviction, and the PRNG offset picks up
-                # at fold len(stash), exactly the pre-eviction state
-                tok = int(stash[-1])
-                self._resumed += 1
-                fid = self._flow_ids.pop(r.id, None)
-                if tr is not None:
-                    tr.instant(r.id + 1, "resumed", now,
-                               replayed=len(stash))
-                    if fid is not None:
-                        tr.flow_end(r.id + 1, "resume", now, fid)
-            else:
-                tok = int(first[i])
-            self.cur = self.cur.at[sid, 0].set(tok)
-            self._temps = self._temps.at[sid].set(sp.temperature)
-            self._top_ks = self._top_ks.at[sid].set(sp.top_k)
-            self._top_ps = self._top_ps.at[sid].set(sp.top_p)
-            self._keys = self._keys.at[sid].set(keys[i])
-            self._offsets = self._offsets.at[sid].set(
-                len(stash) if stash else 1)
-            self._chains[r.id] = chains[i]
-            if self.draft is not None:
-                self._draft_chains[r.id] = dchains[i]
-            s.request = r
-            s.tokens = list(stash) if stash else []
-            s.active = True
-            s.seq = self._admit_seq
-            self._admit_seq += 1
-            if self.on_demand:
-                self._disp_len[sid] = true_lens[i]
-            self._last_admitted_slot = sid
-            self._dirty_slots.add(sid)
-            admitted.append((s, r, tok, stash is not None))
+        with self._span("admit.install", ADMIT_TID):
+            admitted = []
+            for i, (r, sid) in enumerate(zip(group, free)):
+                s = self.slots[sid]
+                sp = r.params
+                stash = self._preempted.pop(r.id, None)
+                if stash:
+                    # resume: the replay prefill's sampled token is
+                    # discarded — the pending decode token is the last
+                    # one emitted before eviction, and the PRNG offset
+                    # picks up at fold len(stash), exactly the
+                    # pre-eviction state
+                    tok = int(stash[-1])
+                    self._resumed += 1
+                    fid = self._flow_ids.pop(r.id, None)
+                    if tr is not None:
+                        tr.instant(r.id + 1, "resumed", now,
+                                   replayed=len(stash))
+                        if fid is not None:
+                            tr.flow_end(r.id + 1, "resume", now, fid)
+                else:
+                    tok = int(first[i])
+                self.cur = self.cur.at[sid, 0].set(tok)
+                self._temps = self._temps.at[sid].set(sp.temperature)
+                self._top_ks = self._top_ks.at[sid].set(sp.top_k)
+                self._top_ps = self._top_ps.at[sid].set(sp.top_p)
+                self._keys = self._keys.at[sid].set(keys[i])
+                self._offsets = self._offsets.at[sid].set(
+                    len(stash) if stash else 1)
+                self._chains[r.id] = chains[i]
+                if self.draft is not None:
+                    self._draft_chains[r.id] = dchains[i]
+                s.request = r
+                s.tokens = list(stash) if stash else []
+                s.active = True
+                s.seq = self._admit_seq
+                self._admit_seq += 1
+                if self.on_demand:
+                    self._disp_len[sid] = true_lens[i]
+                self._last_admitted_slot = sid
+                self._dirty_slots.add(sid)
+                admitted.append((s, r, tok, stash is not None))
         # first-token delivery only after EVERY slot in the group is
         # live (see docstring); resumed requests already streamed their
         # stashed tokens before eviction and re-emit nothing
-        for s, r, tok, resumed in admitted:
-            if not s.active or s.request is not r:
-                continue    # a groupmate's callback aborted it already
-            if resumed:
-                continue
-            self._stats[r.id].first_token_s = now
-            self._emit(s, tok, synced=False)
+        with self._span("admit.emit", ADMIT_TID):
+            for s, r, tok, resumed in admitted:
+                if not s.active or s.request is not r:
+                    continue    # a groupmate's callback aborted it
+                if resumed:
+                    continue
+                self._stats[r.id].first_token_s = now
+                self._emit(s, tok, synced=False)
+
+    def _stamp_admitted(self, group: List[Request], now: float) -> None:
+        """Close the queue wait of each request of an admission group
+        (a first admission or a resume) in its RequestStats."""
+        for r in group:
+            st = self._stats[r.id]
+            st.admitted_s = now
+            st.queued_s += now - self._queued_at.pop(r.id)
 
     # -- dense admission -----------------------------------------------
 
@@ -1959,8 +1970,10 @@ class ServeEngine:
         s = self.slots[slot]
         sp = request.params
         tr = self.trace
+        t_adm = self._now()
+        self._stamp_admitted([request], t_adm)
         if tr is not None:
-            tr.end(request.id + 1, "queued", self._now())
+            tr.end(request.id + 1, "queued", t_adm)
             p0 = time.perf_counter()
         inputs = dict(request.inputs)
         toks = inputs[self._tkey]

@@ -154,6 +154,13 @@ class RequestStats:
     its slot for page pressure and later resumed via prefill-replay —
     the token stream is unaffected (resume is provably identical), only
     latency pays.
+
+    ``admitted_s`` stamps the request's latest admission into a slot
+    (its first, or the resume after a preemption; 0.0 while never
+    admitted), where its ``queued`` trace span ends. ``queued_s`` sums
+    every wait in the queue: submit to first admission, each preemption
+    to its resume, and, for a request that finished while queued
+    (deadline, abort), the wait up to then.
     """
 
     arrival_s: float = 0.0
@@ -165,6 +172,8 @@ class RequestStats:
     accepted: int = 0
     rejected: int = 0
     preemptions: int = 0
+    admitted_s: float = 0.0
+    queued_s: float = 0.0
 
     @property
     def ttft_s(self) -> float:
@@ -194,6 +203,12 @@ class RequestOutput:
     def tok_s(self) -> float:
         dt = self.stats.total_s
         return self.num_generated / dt if dt > 0 else float("inf")
+
+    @property
+    def queue_ms(self) -> float:
+        """Time spent waiting in the engine's queue (ms), summed over
+        every wait (``RequestStats.queued_s``)."""
+        return self.stats.queued_s * 1e3
 
     @property
     def ttft_ms(self) -> float:

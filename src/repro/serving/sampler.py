@@ -27,6 +27,10 @@ the other slots in the fused batch sample normally — and the engine
 retires the offending slot with ``finish_reason='error'`` when the
 sentinel reaches the host walk, so one poisoned request never takes
 down a batch or escapes ``step()`` as an exception.
+
+Both entry points trace under ``jax.named_scope("sampler")``, so a
+profile lays the sampler's device operations (the vocabulary sorts
+among them) to that name.
 """
 
 from __future__ import annotations
@@ -74,10 +78,12 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys, offsets):
     keys (S, 2) u32 -> tokens (S,) i32. Rows with any non-finite logit
     return ``ERR_TOKEN`` (see module docstring) instead of a draw.
     """
-    lg = logits.astype(jnp.float32)
-    toks = jax.vmap(_sample_row)(lg, temps, top_ks, top_ps, keys, offsets)
-    ok = jnp.all(jnp.isfinite(lg), axis=-1)
-    return jnp.where(ok, toks, jnp.int32(ERR_TOKEN))
+    with jax.named_scope("sampler"):
+        lg = logits.astype(jnp.float32)
+        toks = jax.vmap(_sample_row)(lg, temps, top_ks, top_ps, keys,
+                                     offsets)
+        ok = jnp.all(jnp.isfinite(lg), axis=-1)
+        return jnp.where(ok, toks, jnp.int32(ERR_TOKEN))
 
 
 def sample_tokens_scan(logits, temps, top_ks, top_ps, keys, offsets, alive,
@@ -92,4 +98,5 @@ def sample_tokens_scan(logits, temps, top_ks, top_ps, keys, offsets, alive,
     tokens (a dead slot's poisoned logits are masked, not flagged).
     """
     toks = sample_tokens(logits, temps, top_ks, top_ps, keys, offsets)
-    return jnp.where(alive > 0, toks, jnp.int32(pad_id))
+    with jax.named_scope("sampler"):
+        return jnp.where(alive > 0, toks, jnp.int32(pad_id))

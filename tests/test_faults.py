@@ -212,6 +212,22 @@ def test_preemption_victims_ordered_by_priority_then_age(lm):
     assert eng.allocator.pages_in_use == 0
 
 
+def test_queue_wait_sums_the_waits_around_a_preemption(lm):
+    """A preempted request's queue wait sums its first wait and its wait
+    for the resume; its admission stamp is the resume's, so the span
+    from submit to it also holds the residency between the two."""
+    lo = SamplingParams(max_new_tokens=8, eos_id=-1, priority=0)
+    hi = SamplingParams(max_new_tokens=8, eos_id=-1, priority=1)
+    eng = _engine(lm, paged=True, num_pages=5, preempt_limit=16)
+    victim, keeper = _serve(eng, (P1, P2), (lo, hi))
+    assert victim.stats.preemptions >= 1 and keeper.stats.preemptions == 0
+    st = victim.stats
+    assert st.first_token_s < st.admitted_s < st.finished_s
+    assert 0.0 < victim.queue_ms < (st.admitted_s - st.arrival_s) * 1e3
+    assert keeper.queue_ms == pytest.approx(
+        (keeper.stats.admitted_s - keeper.stats.arrival_s) * 1e3)
+
+
 def test_preempt_limit_retires_with_partial_prefix(lm):
     """preempt_limit=0: the first eviction retires the victim as
     'preempted_limit' with its partial tokens (a prefix of its

@@ -13,8 +13,12 @@ the repo-wide nearest-rank percentile (one definition shared by
 ``EngineMetrics`` columns) and the Prometheus text rendering.
 """
 
+import contextlib
 import dataclasses
+import glob
 import json
+import os
+import re
 import socket
 import types
 import urllib.error
@@ -28,8 +32,9 @@ import pytest
 from repro.configs import REGISTRY, reduce_config
 from repro.eval import report as report_mod
 from repro.models import Ctx, build_model
-from repro.obs import (PHASES, SCHED_TID, Histogram, MetricsServer,
-                       TraceConfig, Tracer, percentile, render_prometheus)
+from repro.obs import (ADMIT_SPANS, ADMIT_TID, PHASES, SCHED_TID, Histogram,
+                       MetricsServer, TraceConfig, Tracer, percentile,
+                       render_prometheus)
 from repro.serving import (EngineMetrics, FaultPlan, SamplingParams,
                            ServeEngine, SLATarget, deploy,
                            latency_percentiles)
@@ -121,10 +126,25 @@ def test_histogram_record_mean_percentile():
     assert h.count == 5
     assert h.total == pytest.approx(108.0)
     assert h.mean == pytest.approx(108.0 / 5)
-    # percentile reports the covering bucket's UPPER edge
-    assert h.percentile(50.0) == 4.0               # 3.0 falls in (2, 4]
-    assert h.percentile(0.0) == 1.0                # 0.5 lands in (0, 1]
+    # percentile reports the covering bucket's geometric middle
+    assert h.percentile(50.0) == pytest.approx(8 ** 0.5)   # 3.0 in (2, 4]
+    assert h.percentile(0.0) == pytest.approx(0.5 ** 0.5)  # 0.5 in (0, 1]
     assert Histogram().percentile(95.0) == 0.0     # empty histogram
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_histogram_percentile_within_ten_percent_of_the_samples(seed):
+    """The default quarter-octave buckets read within 2^(1/8) (under
+    10%) of the raw nearest-rank percentile, from 1 ms to a minute."""
+    rng = np.random.default_rng(seed)
+    vals = np.exp(rng.uniform(np.log(1.0), np.log(60_000.0), 500))
+    h = Histogram()
+    for v in vals:
+        h.record(v)
+    assert h.bounds[-1] == pytest.approx(1e-3 * 2 ** 29)   # the old range
+    for q in (0.0, 10.0, 50.0, 90.0, 95.0, 99.0, 100.0):
+        exact = percentile(vals, q)
+        assert abs(h.percentile(q) / exact - 1) < 0.1
 
 
 def test_histogram_overflow_clamps_to_top_edge():
@@ -574,3 +594,128 @@ def test_quant_sweep_traced_records_round_phases():
                                  rows=[r.as_row() for r in rows])
     assert report_mod.load(report_mod.dump(rep)) == rep
     assert rep["rows"][0]["round_phases"] == rp
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: layer scopes in the programs, spans as annotations
+# ---------------------------------------------------------------------------
+
+DECODE_SCOPES = {"embed", "dec.layers", "dec.attn", "dec.cross", "dec.ffn",
+                 "head", "sampler"}
+PREFILL_SCOPES = DECODE_SCOPES | {"enc.attn", "enc.ffn", "dec.cross.kv"}
+
+
+def _scopes(text: str) -> set:
+    """The innermost layer scope of each op_name in a compiled text."""
+    found = set()
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        inner = [c for c in name.split("/") if c in PREFILL_SCOPES]
+        if inner:
+            found.add(inner[-1])
+    return found
+
+
+def _compiled_programs(paged: bool):
+    """The compiled text of a smoke nllb600m engine's decode scan and
+    prefill, lowered with the arguments its dispatch and admission
+    pass."""
+    pipe = deploy("nllb600m", "int4", slots=2, max_len=32, smoke=True,
+                  paged=paged, page_size=4, horizon=4)
+    eng = pipe.engine
+    alive, rem, eos = eng._scan_masks()
+    decode = eng._make_horizon_fn(2).lower(
+        eng.params, eng.cur, eng.cache, eng._temps, eng._top_ks,
+        eng._top_ps, eng._keys, eng._offsets, alive, rem, eos,
+        eng._poison_arr(2)).compile().as_text()
+    src = {"src_tokens": np.zeros((1, 6), np.int32)}
+    if paged:
+        inputs = eng._group_inputs([jnp.zeros((1, 1), jnp.int32)], [src])
+        prefill = eng._prefill_paged_fn.lower(
+            eng.params, inputs, jnp.ones((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1, eng.max_pages), jnp.int32), eng.cache,
+            jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
+            jnp.ones((1,), jnp.float32),
+            jnp.stack([jax.random.PRNGKey(0)]))
+    else:
+        inputs = dict(src, tgt_in=jnp.zeros((1, 1), jnp.int32),
+                      lengths=jnp.ones((1,), jnp.int32))
+        prefill = eng._prefill_fn.lower(
+            eng.params, inputs, jnp.int32(1), jnp.float32(0.0),
+            jnp.int32(0), jnp.float32(1.0), jax.random.PRNGKey(0))
+    return decode, prefill.compile().as_text()
+
+
+def _computations(text: str):
+    """A compiled module's computations with their metadata dropped."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines)
+                 if ln.startswith(("%", "ENTRY")))
+    return [re.sub(r", metadata=\{[^}]*\}", "", ln) for ln in lines[start:]]
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_compiled_programs_carry_the_layer_scopes(paged, monkeypatch):
+    """The decode scan and the prefill name every layer in their
+    operations' op_name metadata, and the scopes are metadata only: with
+    ``jax.named_scope`` made a no-op the compiled computations are the
+    same, instruction for instruction."""
+    decode, prefill = _compiled_programs(paged)
+    assert _scopes(decode) == DECODE_SCOPES
+    assert _scopes(prefill) == PREFILL_SCOPES
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare_decode, bare_prefill = _compiled_programs(paged)
+    assert not _scopes(bare_decode)
+    assert _computations(bare_decode) == _computations(decode)
+    assert _computations(bare_prefill) == _computations(prefill)
+
+
+def _host_events(path):
+    """(thread line, name, start_ns, end_ns) of every host-plane event
+    of a profile written under ``path``."""
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, "the profiler wrote nothing"
+    pd = ProfileData.from_file(files[0])
+    return [(line.name, ev.name.split("#", 1)[0], ev.start_ns, ev.end_ns)
+            for plane in pd.planes if not plane.name.startswith("/device")
+            for line in plane.lines for ev in line.events]
+
+
+def test_rounds_sit_on_the_profilers_host_plane(lm, tmp_path):
+    """Under a running profiler a traced engine's phases and admission
+    sub-spans appear on the host plane by name, each sub-span inside an
+    ``admit`` on the same thread; the Tracer keeps its scheduler track
+    to the round and its four phases, and the sub-spans on their own."""
+    eng = _engine(lm, paged=True, horizon=4, trace=TraceConfig(),
+                  faults=FaultPlan())
+    with jax.profiler.trace(str(tmp_path)):
+        _serve(eng, (P1, P2, P3), (GREEDY8, SAMPLED6, GREEDY8))
+    events = _host_events(str(tmp_path))
+    names = {n for _, n, _, _ in events}
+    assert set(PHASES) | set(ADMIT_SPANS) <= names
+    admits = [(t, a, b) for t, n, a, b in events if n == "admit"]
+    subs = [(t, n, a, b) for t, n, a, b in events if n in ADMIT_SPANS]
+    for t, n, a, b in subs:
+        assert any(t == t2 and a2 <= a and b <= b2
+                   for t2, a2, b2 in admits), n
+    tr = eng.trace
+    assert tr.check() == []
+    sched = [e for e in tr.events if e.tid == SCHED_TID]
+    assert {e.name for e in sched if e.ph == "X"} == set(PHASES)
+    assert {e.name for e in sched if e.ph != "X"} == {"round"}
+    admit_track = [e for e in tr.events if e.tid == ADMIT_TID]
+    assert {e.name for e in admit_track} == set(ADMIT_SPANS)
+    assert all(e.ph == "X" for e in admit_track)
+    # each sub-span lies inside an admit phase on the engine clock too
+    phases = [(e.ts_us, e.ts_us + e.dur_us) for e in sched
+              if e.ph == "X" and e.name == "admit"]
+    for e in admit_track:
+        assert any(a - 1.0 <= e.ts_us and e.ts_us + e.dur_us <= b + 1.0
+                   for a, b in phases), e.name
+    tracks = {e["tid"]: e["args"]["name"] for e in tr.to_chrome()["traceEvents"]
+              if e["name"] == "thread_name"}
+    assert tracks[ADMIT_TID] == "admission"

@@ -257,6 +257,33 @@ def test_engine_queue_overcommit_and_stats():
         assert o.stats.finished_s >= o.stats.first_token_s >= o.stats.arrival_s
 
 
+def test_request_stats_stamp_admission_and_queue_wait():
+    """Every output carries when its request was admitted and how long
+    it waited queued: a request admitted at once waited next to
+    nothing, one queued behind a full engine waited until a slot
+    freed, and one aborted while queued waited until the abort."""
+    rc, model, params = _lm()
+    eng = ServeEngine(model, params, slots=1, max_len=16, ctx=CTX)
+    p = jax.random.randint(jax.random.PRNGKey(0), (1, 4), 0, rc.vocab_size)
+    first = eng.submit({"tokens": p}, SamplingParams(max_new_tokens=3))
+    second = eng.submit({"tokens": p}, SamplingParams(max_new_tokens=3))
+    third = eng.submit({"tokens": p}, SamplingParams(max_new_tokens=3))
+    gone = eng.abort(third)
+    outs = {o.request_id: o for o in eng.run_until_drained()}
+    a, b = outs[first], outs[second]
+    for o in (a, b):
+        st = o.stats
+        assert st.arrival_s <= st.admitted_s <= st.first_token_s
+        assert o.queue_ms == pytest.approx(
+            (st.admitted_s - st.arrival_s) * 1e3)
+    # the second waited for the first to finish and free the one slot
+    assert b.stats.admitted_s >= a.stats.finished_s
+    assert b.queue_ms > a.queue_ms >= 0.0
+    assert gone.stats.admitted_s == 0.0
+    assert gone.queue_ms == pytest.approx(
+        (gone.stats.finished_s - gone.stats.arrival_s) * 1e3)
+
+
 def test_abort_request():
     rc, model, params = _lm()
     eng = ServeEngine(model, params, slots=1, max_len=16, ctx=CTX)
