@@ -315,6 +315,7 @@ class ServeEngine:
         self._last_admitted_slot = -1
         self._decode_steps = 0            # occupancy accounting
         self._active_slot_steps = 0
+        self._sampler_full_steps = 0      # micro-steps a live slot sampled
         self._page_slot_steps = 0
         self._decode_syncs = 0            # host-overhead accounting
         self._synced_tokens = 0
@@ -916,6 +917,7 @@ class ServeEngine:
         with self._span("dispatch", K=1):
             self._grow_chains(1)
             self._decode_steps += 1
+            self._sampler_full_steps += self._sampling()
             self._active_slot_steps += sum(s.active for s in self.slots)
             if self.paged:
                 self._page_slot_steps += self.allocator.pages_in_use
@@ -936,6 +938,12 @@ class ServeEngine:
                         tr.instant(s.request.id + 1, "decode-round",
                                    self._now(), planned=1)
                     self._emit(s, int(nxt_host[s.id]))
+
+    def _sampling(self) -> bool:
+        """Whether an active slot samples (``temperature > 0``), so the
+        sampler's full path runs; a greedy batch takes its argmax only."""
+        return any(s.active and not s.request.params.greedy
+                   for s in self.slots)
 
     def _dispatch_horizon(self, K: int, carry=None):
         """Dispatch one K-step fused horizon WITHOUT syncing its block.
@@ -961,6 +969,7 @@ class ServeEngine:
         with self._span("dispatch", K=K):
             self._grow_chains(K)
             self._decode_steps += K
+            self._sampler_full_steps += K * self._sampling()
             if self.paged:
                 self._page_slot_steps += K * self.allocator.pages_in_use
             fn = self._horizon_fns.get(K)
@@ -1158,6 +1167,7 @@ class ServeEngine:
             decode_syncs=self._decode_syncs,
             synced_tokens=self._synced_tokens,
             active_slot_steps=self._active_slot_steps,
+            sampler_full_steps=self._sampler_full_steps,
             page_slot_steps=self._page_slot_steps,
             overlap_rounds=self._overlap_rounds,
             verify_calls=self._verify_calls,
@@ -1211,6 +1221,7 @@ class ServeEngine:
         and are unaffected."""
         self._decode_steps = 0
         self._active_slot_steps = 0
+        self._sampler_full_steps = 0
         self._page_slot_steps = 0
         self._decode_syncs = 0
         self._synced_tokens = 0

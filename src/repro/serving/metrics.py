@@ -53,6 +53,7 @@ class EngineMetrics:
     decode_syncs: int            # host blocks on a device token buffer
     synced_tokens: int           # tokens actually emitted to requests
     active_slot_steps: int       # slot-steps that served a live request
+    sampler_full_steps: int      # micro-steps dispatched with a sampled slot
     page_slot_steps: int         # page-steps attended (paged occupancy basis)
     overlap_rounds: int          # horizons dispatched before the previous sync
     # speculative-decoding counters
@@ -140,6 +141,7 @@ def merge_metrics(snapshots: Sequence[EngineMetrics],
         decode_syncs=decode_syncs,
         synced_tokens=synced_tokens,
         active_slot_steps=tot("active_slot_steps"),
+        sampler_full_steps=tot("sampler_full_steps"),
         page_slot_steps=tot("page_slot_steps"),
         overlap_rounds=tot("overlap_rounds"),
         verify_calls=verify_calls,

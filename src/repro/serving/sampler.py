@@ -4,8 +4,9 @@ The engine decodes all slots in one batched step; slots may carry
 different SamplingParams (greedy next to nucleus-sampled). To keep a
 single compiled function regardless of the mix, the per-slot knobs
 (temperature / top_k / top_p / PRNG key / stream offset) enter as traced
-arrays and the greedy-vs-sampled choice is a data-dependent `where` —
-changing a request's params never recompiles, only re-runs.
+arrays and the greedy-vs-sampled choice is data-dependent (a `where`
+per row, a `cond` per batch) — changing a request's params never
+recompiles, only re-runs.
 
 Per-slot PRNG streams: each request owns a base key derived from its
 ``seed``; token ``t`` of that request draws from ``fold_in(key, t)``, so
@@ -27,6 +28,14 @@ the other slots in the fused batch sample normally — and the engine
 retires the offending slot with ``finish_reason='error'`` when the
 sentinel reaches the host walk, so one poisoned request never takes
 down a batch or escapes ``step()`` as an exception.
+
+Greedy batches skip the sampling path: the full path sorts the whole
+vocabulary twice per row (top-k, then top-p), and under ``vmap`` the
+per-row ``where`` runs it for every row whatever its temperature. A
+``lax.cond`` outside the ``vmap`` therefore takes a plain row-wise
+argmax whenever no live row has ``temperature > 0``, and the full
+path otherwise. The greedy branch returns exactly what the full path
+returns for a greedy row, so the choice changes cost, never tokens.
 
 Both entry points trace under ``jax.named_scope("sampler")``, so a
 profile lays the sampler's device operations (the vocabulary sorts
@@ -71,6 +80,25 @@ def _sample_row(logits, temp, top_k, top_p, key, offset):
     return jnp.where(temp <= 0.0, greedy, tok).astype(jnp.int32)
 
 
+def _sample(logits, temps, top_ks, top_ps, keys, offsets, live=True):
+    """``sample_tokens`` with a (S,) bool ``live`` mask: only live rows
+    decide whether the full sampling path runs (see module docstring);
+    a greedy batch takes the row-wise argmax alone."""
+    with jax.named_scope("sampler"):
+        lg = logits.astype(jnp.float32)
+
+        def full(lg):
+            return jax.vmap(_sample_row)(lg, temps, top_ks, top_ps, keys,
+                                         offsets)
+
+        def greedy(lg):
+            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+        toks = jax.lax.cond(jnp.any((temps > 0.0) & live), full, greedy, lg)
+        ok = jnp.all(jnp.isfinite(lg), axis=-1)
+        return jnp.where(ok, toks, jnp.int32(ERR_TOKEN))
+
+
 def sample_tokens(logits, temps, top_ks, top_ps, keys, offsets):
     """Batched next-token sampling across slots.
 
@@ -78,12 +106,7 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys, offsets):
     keys (S, 2) u32 -> tokens (S,) i32. Rows with any non-finite logit
     return ``ERR_TOKEN`` (see module docstring) instead of a draw.
     """
-    with jax.named_scope("sampler"):
-        lg = logits.astype(jnp.float32)
-        toks = jax.vmap(_sample_row)(lg, temps, top_ks, top_ps, keys,
-                                     offsets)
-        ok = jnp.all(jnp.isfinite(lg), axis=-1)
-        return jnp.where(ok, toks, jnp.int32(ERR_TOKEN))
+    return _sample(logits, temps, top_ks, top_ps, keys, offsets)
 
 
 def sample_tokens_scan(logits, temps, top_ks, top_ps, keys, offsets, alive,
@@ -95,8 +118,11 @@ def sample_tokens_scan(logits, temps, top_ks, top_ps, keys, offsets, alive,
     in the horizon (EOS or exhausted ``max_new_tokens`` budget) emit
     ``pad_id`` — the host-side walk of the emitted token block stops at
     each slot's retirement point, so pads are never read as generated
-    tokens (a dead slot's poisoned logits are masked, not flagged).
+    tokens (a dead slot's poisoned logits are masked, not flagged). A
+    dead slot keeps its last request's temperature, so only live slots
+    decide whether the full sampling path runs.
     """
-    toks = sample_tokens(logits, temps, top_ks, top_ps, keys, offsets)
+    live = alive > 0
+    toks = _sample(logits, temps, top_ks, top_ps, keys, offsets, live)
     with jax.named_scope("sampler"):
-        return jnp.where(alive > 0, toks, jnp.int32(pad_id))
+        return jnp.where(live, toks, jnp.int32(pad_id))
